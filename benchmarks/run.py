@@ -36,8 +36,13 @@ def _snapshot(section: str, rows, error: str | None = None) -> None:
     path.write_text(json.dumps(payload, indent=2, default=str) + "\n")
 
 
-def main() -> None:
+def main() -> int:
+    """Run every section (or the one named in argv); returns 1 when any
+    section raised, after printing and snapshotting its error."""
     only = sys.argv[1] if len(sys.argv) > 1 else None
+    from repro.common.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         analysis, kernels, microbench, optimality, roofline, serving,
         tables,
@@ -59,6 +64,7 @@ def main() -> None:
         "analysis": analysis.run,
     }
     print("name,us_per_call,derived")
+    failed = 0
     for name, fn in sections.items():
         if only and only != name:
             continue
@@ -68,6 +74,7 @@ def main() -> None:
             err = f"{type(e).__name__}: {e}"
             _emit(name, "", {"error": err})
             _snapshot(name, [], error=err)
+            failed += 1
             continue
         for i, row in enumerate(rows):
             us = row.get("us_per_call")
@@ -79,7 +86,8 @@ def main() -> None:
                         break
             _emit(f"{name}[{i}]", "" if us is None else us, row)
         _snapshot(name, list(rows))
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -20,15 +20,13 @@ GB = 1024**3
 TASKS = ("retrieval", "classify", "vqa")
 
 
-def _deployment():
-    from repro.configs.s2m3_zoo import get_clip_config
-    from repro.core.cluster import ClusterSpec, DeviceSpec
+def clip_models(ccfg, params):
+    """The mini-CLIP tasks (retrieval, classify, vqa) over one shared
+    vision and one shared text encoder: their ``ModelSpec``s and the
+    module builders for ``Deployment.add_model``."""
     from repro.core.module import ModelSpec, ModuleSpec
     from repro.models import clip as C
-    from repro.s2m3 import Deployment
 
-    ccfg = get_clip_config("mini-clip")
-    params = C.init_clip(jax.random.PRNGKey(0), ccfg)
     vis = ModuleSpec("mini-vit", "encoder", "vision", 60_000,
                      flops_per_query=2e6)
     txt = ModuleSpec("mini-trf", "encoder", "text", 50_000,
@@ -59,6 +57,18 @@ def _deployment():
                   ModuleSpec("mini-lm", "head", "task", 80_000,
                              flops_per_query=4e6)),
     ]
+    return models, builders
+
+
+def _deployment():
+    from repro.configs.s2m3_zoo import get_clip_config
+    from repro.core.cluster import ClusterSpec, DeviceSpec
+    from repro.models import clip as C
+    from repro.s2m3 import Deployment
+
+    ccfg = get_clip_config("mini-clip")
+    params = C.init_clip(jax.random.PRNGKey(0), ccfg)
+    models, builders = clip_models(ccfg, params)
     cluster = ClusterSpec(devices=[
         DeviceSpec(f"dev{i}", 1 * GB, (2.0 if i < 2 else 1.0) * 1e9)
         for i in range(4)

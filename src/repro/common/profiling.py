@@ -77,16 +77,11 @@ def collective_stats(hlo_text: str) -> CollectiveStats:
 
 
 def cost_summary(compiled) -> dict:
-    """Pull flops/bytes out of compiled.cost_analysis() across jax versions."""
+    """Pull flops/bytes out of ``compiled.cost_analysis()`` (a dict)."""
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
-    flops = float(ca.get("flops", 0.0))
-    # bytes accessed may be split across keys depending on version
-    byts = float(ca.get("bytes accessed", 0.0))
-    if byts == 0.0:
-        byts = sum(float(v) for k, v in ca.items() if k.startswith("bytes accessed"))
-    return {"flops": flops, "bytes": byts, "raw_keys": sorted(ca)[:8]}
+    return {"flops": float(ca.get("flops", 0.0)),
+            "bytes": float(ca.get("bytes accessed", 0.0)),
+            "raw_keys": sorted(ca)[:8]}
 
 
 def memory_summary(compiled) -> dict:

@@ -287,7 +287,8 @@ def _sweep(jobs: int, multi_pod_only: bool, force: bool):
             log = OUT_DIR / f"log_{arch}__{shape}__{'mp' if mp else 'sp'}.txt"
             p = subprocess.Popen(
                 cmd, stdout=log.open("w"), stderr=subprocess.STDOUT,
-                env={**os.environ, "PYTHONPATH": str(REPO / "src")})
+                env={**os.environ, "PYTHONPATH": str(REPO / "src"),
+                     "JAX_PLATFORMS": "cpu"})
             procs.append((p, cells[idx]))
             idx += 1
         done = [(p, c) for p, c in procs if p.poll() is not None]

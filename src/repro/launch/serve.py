@@ -48,6 +48,9 @@ def main():
                     help="routing policy for --plan (paper | queue_aware)")
     args = ap.parse_args()
 
+    from repro.common.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import jax.numpy as jnp
     import numpy as np
 

@@ -175,7 +175,6 @@ def decode_attention_shardmap(q, k_cache, v_cache, lengths, *, mesh, rules,
     import math as _math
 
     from repro.common.sharding import spec_for
-    from repro.layers.moe import shard_map_compat
 
     B, _, H, D = q.shape
     T, K = k_cache.shape[1], k_cache.shape[2]
@@ -234,10 +233,10 @@ def decode_attention_shardmap(q, k_cache, v_cache, lengths, *, mesh, rules,
         out = o / jnp.maximum(s, 1e-30).transpose(0, 2, 1)[..., None]
         return out.astype(q_l.dtype)
 
-    return shard_map_compat(
-        f, mesh,
+    return jax.shard_map(
+        f, mesh=mesh,
         in_specs=(spec_q, spec_c, spec_c, spec_l),
-        out_specs=spec_q,
+        out_specs=spec_q, check_vma=False,
     )(q, k_cache, v_cache, lengths)
 
 
@@ -300,7 +299,6 @@ def _cache_insert_shardmap(cache_arr, new_val, lengths, mesh, rules):
     import numpy as np
 
     from repro.common.sharding import spec_for
-    from repro.layers.moe import shard_map_compat
 
     nd = cache_arr.ndim
     axes_c = ("cache_batch", "cache_seq") + (None,) * (nd - 2)
@@ -328,6 +326,7 @@ def _cache_insert_shardmap(cache_arr, new_val, lengths, mesh, rules):
         new_rows = jnp.where(mask, nv[:, 0].astype(c.dtype), old)
         return c.at[rows, posc].set(new_rows)
 
-    return shard_map_compat(
-        f, mesh, in_specs=(spec_c, spec_n, spec_l), out_specs=spec_c,
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=(spec_c, spec_n, spec_l), out_specs=spec_c,
+        check_vma=False,
     )(cache_arr, new_val, lengths)

@@ -31,19 +31,6 @@ from repro.layers.initializers import WSpec
 from repro.layers.mlp import activation, mlp_apply, mlp_specs
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    # jax >= 0.5 exposes jax.shard_map (check_vma kwarg); older releases
-    # raise AttributeError on the lookup and ship it under experimental
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                             check_vma=False)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_rep=False)
-
-
 def padded_experts(cfg) -> int:
     return cfg.expert_pad_to or cfg.n_experts
 
@@ -173,10 +160,10 @@ def moe_apply_ep(params, x, cfg, mesh, *, capacity_factor: float = 1.25,
             aux = jax.lax.pmean(aux, dp_spec)
         return y.reshape(x_loc.shape), aux
 
-    y, aux = shard_map_compat(
-        f, mesh,
+    y, aux = jax.shard_map(
+        f, mesh=mesh,
         in_specs=(x_spec, P(None, None), w_spec, w_spec, wo_spec),
-        out_specs=(x_spec, P()),
+        out_specs=(x_spec, P()), check_vma=False,
     )(x, params["router"], params["wi_gate"], params["wi_up"], params["wo"])
 
     if cfg.n_shared_experts:

@@ -3,6 +3,7 @@ a multi-device host): proves lower+compile works for a (2,2) and a
 (2,2,2) multi-pod mesh over the same machinery as launch/dryrun.py."""
 
 import json
+import os
 import pathlib
 import subprocess
 import sys
@@ -26,10 +27,10 @@ from repro.training.optimizer import state_specs
 from repro.training.train_step import make_train_step
 
 multi_pod = %(multi_pod)s
-if multi_pod:
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-else:
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh_shape, axes = (((2, 2, 2), ("pod", "data", "model")) if multi_pod
+                    else ((2, 2), ("data", "model")))
+mesh = jax.make_mesh(mesh_shape, axes,
+                     axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 cfg = get_config("%(arch)s", smoke=True)
 rules = merge_rules(None)
 bundle = build_model(cfg, mesh=mesh, rules=rules)
@@ -56,7 +57,8 @@ def _run(arch, multi_pod):
     code = SCRIPT % {"arch": arch, "multi_pod": multi_pod}
     out = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,
-        env={"PYTHONPATH": str(REPO / "src"), "PATH": "/usr/bin:/bin"},
+        env={**os.environ, "PYTHONPATH": str(REPO / "src"),
+             "JAX_PLATFORMS": "cpu"},
         timeout=600, cwd=str(REPO))
     assert out.returncode == 0, out.stderr[-2000:]
     return json.loads(out.stdout.strip().splitlines()[-1])
