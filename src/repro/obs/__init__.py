@@ -1,6 +1,6 @@
 """``repro.obs`` — observability for the S2M3 serving stack.
 
-Three layers, threaded through ``serving/{engine,scheduler,decode}``
+Four layers, threaded through ``serving/{engine,scheduler,decode}``
 and surfaced on the ``s2m3.Deployment`` facade:
 
 * **Tracing** (``obs.trace``): ``Span``/``Tracer`` with an injectable
@@ -8,10 +8,17 @@ and surfaced on the ``s2m3.Deployment`` facade:
   admission wait, batch formation, encoder launches (tagged with their
   cross-task composition), prefill, and every paged-decode tick, keyed
   by request id so one request's life is one trace tree.
+  A decode tick also records its four host phases (``tick.form``,
+  ``tick.dispatch``, ``tick.sample``, ``tick.commit``) once, under the
+  rid and root span of its first live row: that request's tree and
+  Chrome track carry the batch's phases, which the other rows share.
   ``Trace.to_chrome_trace()`` exports Chrome/Perfetto JSON.
+* **Compile spans** (``obs.compiles``): JAX's trace, lower and backend
+  compile events as spans on one process-level tracer; importing
+  ``repro.s2m3`` installs the listener.
 * **Metrics** (``obs.metrics``): a lock-safe counter/gauge/histogram
-  registry.  The scheduler, the decode streams, the ``PagePool`` and
-  the engine register instruments on it; ``stats_dict()`` remains as a
+  registry.  The scheduler, the decode streams and the ``PagePool``
+  register instruments on it; ``stats_dict()`` remains as a
   compatibility view.  ``obs.summary.slo_summary`` renders per-task
   p50/p99 and SLO-deadline attainment from the histograms.
 * **Drift** (``obs.drift``): ``Deployment.compare(workload)`` runs

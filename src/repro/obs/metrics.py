@@ -1,7 +1,7 @@
 """Lock-safe metrics registry: counters, gauges, histograms.
 
-One ``MetricsRegistry`` per serving scheduler (and one per engine for
-engine-lifetime counters).  Instruments are get-or-created by name +
+One ``MetricsRegistry`` per serving scheduler, shared by its decode
+streams and page pools.  Instruments are get-or-created by name +
 labels — ``reg.counter("serve.calls", module="mini-vit")`` — and every
 instrument mutation happens under the registry's lock, which each
 instrument holds a reference to.  That invariant is enforced statically

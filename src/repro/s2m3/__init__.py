@@ -21,6 +21,7 @@ named strategies without touching core.
 """
 
 from repro.core.routing import QueueSnapshot, Request, SimResult  # noqa: F401
+from repro.obs import compiles as _compiles
 from repro.s2m3.deployment import Deployment, PlanReport  # noqa: F401
 from repro.s2m3.policies import (  # noqa: F401
     RouteQuery,
@@ -31,6 +32,10 @@ from repro.s2m3.policies import (  # noqa: F401
     register_placement,
     register_routing,
 )
+
+# the process's compile spans (repro.obs.compiles), from before a
+# deployment's first compile: its weights', warm-up's and served programs'
+_compiles.install()
 
 __all__ = [
     "Deployment", "PlanReport", "Request", "SimResult", "QueueSnapshot",

@@ -273,7 +273,15 @@ class DecodeStream:
 
     def _decode_once(self) -> tuple[list[_GenSeq], int]:
         """One batched decode step over all live rows.  Batch formation
-        (incl. page extension) under the lock; dispatch outside it."""
+        (incl. page extension) under the lock; dispatch outside it.
+
+        Besides one ``decode_tick`` span per live row, the tick records
+        its host phases once each (``tick.form``, ``tick.dispatch``,
+        ``tick.sample``, ``tick.commit``) under the first live row's rid
+        and root span; dispatch and sample split ``decode_tick`` exactly.
+        The phases cover every live row's work, so a per-request sum of
+        spans charges the first row with the whole tick's phases."""
+        t_form = self._now()
         with self._lock:
             tokens = np.zeros((self.rows.max_slots, 1), np.int32)
             live = sorted(self.live.items())
@@ -289,6 +297,7 @@ class DecodeStream:
                 tokens[row, 0] = seq.tokens[-1]
             tables = self.tables.copy()
             lengths = self.lengths.copy()
+            t_formed = self._now()
             pages_live = self.pool.n_live_pages
             self._c_steps.inc()
             if len({seq.request.model for _, seq in live}) >= 2:
@@ -297,6 +306,7 @@ class DecodeStream:
         logits, cache = self.engine.apply_paged_decode(
             self.module, jnp.asarray(tokens), self.cache,
             jnp.asarray(tables), jnp.asarray(lengths))
+        t_sample = self._now()
         self.cache = cache
         picks: dict[int, int] = {}
         for row, seq in live:
@@ -321,6 +331,14 @@ class DecodeStream:
                         self.tracer.end(seq.decode_sid, t1=self._now()))
                     self._finish_locked(seq)
                     finished.append(seq)
+        t_end = self._now()
+        first = live[0][1]
+        for phase, a, b in (("tick.form", t_form, t_formed),
+                            ("tick.dispatch", t0, t_sample),
+                            ("tick.sample", t_sample, t1),
+                            ("tick.commit", t1, t_end)):
+            self.tracer.record(self.module, phase, a, b, rid=first.rid,
+                               parent=first.parent, rows=len(live))
         return finished, len(live)
 
     def tick(self) -> TickReport:

@@ -27,7 +27,6 @@ import jax
 from repro.core.module import ModelSpec, ModuleSpec
 from repro.core.placement import Placement
 from repro.core.registry import ModuleRegistry
-from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import Span, Tracer
 
 
@@ -100,9 +99,6 @@ class S2M3Engine:
         # solo infer()/generate() spans land here; the serving scheduler
         # uses its own epoch-relative tracer for the batched paths
         self.tracer = tracer or Tracer()
-        # engine-lifetime instruments (per-module call counts); each
-        # ServeScheduler keeps its own per-run registry on top
-        self.metrics = MetricsRegistry()
         self.runtimes: dict[str, ModuleRuntime] = {}
         self.decoders: dict[str, DecoderRuntime] = {}
         self.device_map = device_map or {"dev0": jax.devices()[0]}
@@ -258,7 +254,6 @@ class S2M3Engine:
         used = host if host is not None and host in self.device_map else rt.host
         params = self.params_on(module_name, used)
         x = jax.device_put(x, self._device_for(used))
-        self.metrics.counter("engine.module_calls", module=module_name).inc()
         return rt.apply(params, x), used
 
     def apply_head(self, module_name: str, enc_outputs: dict[str, Any],
@@ -271,7 +266,6 @@ class S2M3Engine:
         params = self.params_on(module_name, used)
         dev = self._device_for(used)
         moved = {k: jax.device_put(v, dev) for k, v in enc_outputs.items()}
-        self.metrics.counter("engine.head_calls", module=module_name).inc()
         return rt.apply(params, moved, **(head_extra or {})), used
 
     # -- generative (decoder-head) path ---------------------------------
@@ -313,7 +307,6 @@ class S2M3Engine:
         (last-token logits, filled dense cache)."""
         rt = self.decoder_runtime(module_name)
         batch = {k: jax.device_put(v, rt.device) for k, v in batch.items()}
-        self.metrics.counter("engine.prefills", module=module_name).inc()
         return rt.prefill_jit(rt.params, batch, cache)
 
     def apply_paged_decode(self, module_name: str, tokens, cache,
@@ -326,7 +319,6 @@ class S2M3Engine:
             raise NotImplementedError(
                 f"decoder {module_name!r} (family "
                 f"{rt.bundle.cfg.family!r}) has no paged decode path")
-        self.metrics.counter("engine.decode_steps", module=module_name).inc()
         return rt.paged_decode_jit(rt.params, tokens, cache,
                                    block_tables, lengths)
 

@@ -259,6 +259,111 @@ def test_serve_trace_is_one_contiguous_tree_per_request(vlm_deployment,
         assert any(stage == "decode" for _, stage, _, _ in r.timeline)
 
 
+def test_decode_tick_records_four_host_phases(vlm_deployment):
+    """Each tick with live rows records tick.form, tick.dispatch,
+    tick.sample and tick.commit once; dispatch and sample split the
+    tick's decode_tick span exactly, and every phase sits under the
+    first live row's root span."""
+    dep, cfg = vlm_deployment
+    reqs = _vlm_workload(cfg, n=4)
+    dep.serve(reqs, **_SERVE_KW)
+    trace = dep.trace()
+    assert trace.validate() == []
+    ticks: dict = {}
+    for s in trace.spans:
+        if s.phase == "decode_tick":
+            ticks.setdefault((s.t0, s.t1), []).append(s)
+    phases = {p: sorted((s for s in trace.spans if s.phase == p),
+                        key=lambda s: s.t0)
+              for p in ("tick.form", "tick.dispatch", "tick.sample",
+                        "tick.commit")}
+    assert ticks and all(len(v) == len(ticks) for v in phases.values())
+    for (t0, t1), rows, form, disp, samp, commit in zip(
+            sorted(ticks), (ticks[k] for k in sorted(ticks)),
+            *phases.values()):
+        assert disp.t0 == t0 and samp.t1 == t1 and disp.t1 == samp.t0
+        assert form.t1 <= disp.t0 and commit.t0 >= samp.t1
+        first = min(rows, key=lambda s: s.sid)     # rows record in order
+        for s in (form, disp, samp, commit):
+            assert s.name == "vlm-head"
+            assert s.rid == first.rid and s.attrs["rows"] == len(rows)
+            assert s.parent == trace.tree(first.rid).sid
+    # the residency span still parents only decode ticks
+    for q in reqs:
+        decode = next(s for s in trace.spans_for(q.rid)
+                      if s.phase == "decode")
+        assert {k.phase for k in trace.children(decode.sid)} \
+            == {"decode_tick"}
+
+
+def test_compile_recorder_spans_a_fresh_jit_once():
+    """A freshly jitted function adds one compile.backend span named
+    after it, on the perf_counter clock; a second install registers no
+    second listener; compile spans stay out of the serving trace."""
+    import time
+
+    from repro.obs import compiles
+
+    rec = compiles.install()
+    assert compiles.install() is rec and compiles.recorder() is rec
+
+    def obs_fresh_probe(x):
+        return 3.0 * x + 1.0
+
+    n0 = len(rec.tracer.trace)
+    before = time.perf_counter()
+    jax.jit(obs_fresh_probe)(jnp.arange(5.0)).block_until_ready()
+    after = time.perf_counter()
+    new = rec.tracer.trace.spans[n0:]
+    backend = [s for s in new if s.phase == "compile.backend"
+               and "obs_fresh_probe" in s.name]
+    assert len(backend) == 1
+    assert {s.phase for s in new if "obs_fresh_probe" in s.name} \
+        == {"compile.trace", "compile.lower", "compile.backend"}
+    # JAX's wall-clock stamps (~1e9 s) carry ~0.25 us of float rounding
+    assert before - 1e-6 <= backend[0].t0 <= backend[0].t1 <= after + 1e-6
+
+
+def test_compile_spans_stay_out_of_the_serving_trace(vlm_deployment):
+    from repro.obs import compiles
+
+    dep, cfg = vlm_deployment
+    reqs = _vlm_workload(cfg, n=2)
+    dep.serve(reqs, **_SERVE_KW)
+    assert compiles.recorder() is not None    # importing repro.s2m3 did
+    trace = dep.trace()
+    assert not [s for s in trace.spans if s.phase.startswith("compile.")]
+    tids = {e["tid"] for e in trace.to_chrome_trace()["traceEvents"]}
+    assert tids == {q.rid for q in reqs}
+
+
+def test_importing_the_facade_records_compiles_before_any_engine():
+    """A fresh process that imports ``repro.s2m3`` records the compiles
+    that come before an engine exists, as a deployment's weight draw."""
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import repro.s2m3\n"
+        "from repro.obs import compiles\n"
+        "import jax, jax.numpy as jnp\n"
+        "def weight_draw(k):\n"
+        "    return jax.random.normal(k, (4, 4), jnp.float32)\n"
+        "jax.jit(weight_draw)(jax.random.PRNGKey(0)).block_until_ready()\n"
+        "print(sorted({s.phase for s in compiles.recorder().tracer.trace.spans\n"
+        "              if 'weight_draw' in s.name}))\n")
+    src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "PYTHONPATH": os.pathsep.join(
+               [src] + [p for p in [os.environ.get("PYTHONPATH")] if p])}
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split("\n")[-2] == str(
+        ["compile.backend", "compile.lower", "compile.trace"])
+
+
 def test_scheduler_metrics_power_slo_summary(vlm_deployment):
     dep, cfg = vlm_deployment
     reqs = _vlm_workload(cfg, n=4)
@@ -349,8 +454,7 @@ def test_pagepool_registers_occupancy_instruments(vlm_deployment):
     assert mt.value("pagepool.pages_peak", module="vlm-head") > 1
     assert mt.value("pagepool.page_allocs", module="vlm-head") > 0
     assert mt.value("pagepool.seq_frees", module="vlm-head") == 3
-    # engine-lifetime counters tick independently of the scheduler's
-    assert dep.engine.metrics.total("engine.decode_steps") > 0
+    assert dep.scheduler.metrics.total("decode.steps") > 0
 
 
 def test_obs_self_test_passes():
