@@ -28,6 +28,7 @@ from __future__ import annotations
 import heapq
 import threading
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Any
 
 import jax
@@ -111,6 +112,12 @@ class DecodeStream:
                                                 module=module)
         self._c_xtask = self.metrics.counter("decode.cross_task_batches",
                                              module=module)
+        self._c_batched = self.metrics.counter("decode.batched_picks",
+                                               module=module)
+        # greedy rows' tokens in one device call over all rows' logits;
+        # select_token is looked up now, so a patched one is traced
+        self._pick = jax.jit(partial(select_token, rng=None,
+                                     temperature=0.0))
 
     # legacy counter attributes, now views over the metrics registry
     @property
@@ -228,9 +235,13 @@ class DecodeStream:
         batch = self.engine.gen_batch(req.prompt, seq.enc_outputs)
         logits, one = self.engine.apply_prefill(self.module, batch, one)
         self.cache = insert_pages(self.cache, one, pages, seq.length)
-        seq.rng = jax.random.PRNGKey((seq.rid or 0) & 0x7FFFFFFF)
-        seq.rng, k = jax.random.split(seq.rng)
-        tok = int(select_token(logits[0], k, temperature=req.temperature))
+        if req.temperature <= 0:
+            tok = int(np.asarray(self._pick(logits))[0])
+        else:
+            seq.rng = jax.random.PRNGKey((seq.rid or 0) & 0x7FFFFFFF)
+            seq.rng, k = jax.random.split(seq.rng)
+            tok = int(select_token(logits[0], k,
+                                   temperature=req.temperature))
         seq.tokens.append(tok)
         span = self.tracer.record(self.module, "prefill", t0, self._now(),
                                   rid=seq.rid, parent=seq.parent,
@@ -274,6 +285,9 @@ class DecodeStream:
     def _decode_once(self) -> tuple[list[_GenSeq], int]:
         """One batched decode step over all live rows.  Batch formation
         (incl. page extension) under the lock; dispatch outside it.
+        Greedy rows' tokens come from one ``_pick`` call over every
+        row's logits and one host copy; rows with ``temperature > 0``
+        split their own key and sample one by one.
 
         Besides one ``decode_tick`` span per live row, the tick records
         its host phases once each (``tick.form``, ``tick.dispatch``,
@@ -308,8 +322,14 @@ class DecodeStream:
             jnp.asarray(tables), jnp.asarray(lengths))
         t_sample = self._now()
         self.cache = cache
+        greedy = {row for row, seq in live
+                  if seq.request.temperature <= 0}
+        picked = np.asarray(self._pick(logits)) if greedy else None
         picks: dict[int, int] = {}
         for row, seq in live:
+            if row in greedy:
+                picks[row] = int(picked[row])
+                continue
             seq.rng, k = jax.random.split(seq.rng)
             picks[row] = int(select_token(
                 logits[row], k, temperature=seq.request.temperature))
@@ -320,6 +340,7 @@ class DecodeStream:
                                rows=len(live), pages_live=pages_live)
         finished = []
         with self._lock:
+            self._c_batched.inc(len(greedy))
             for row, seq in live:
                 seq.length += 1
                 self.lengths[row] = seq.length

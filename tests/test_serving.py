@@ -14,7 +14,10 @@ from repro.core.module import ModelSpec, ModuleSpec
 from repro.core.routing import Request
 from repro.models import clip as C
 from repro.models.api import build_model
+import repro.serving.decode as decode_mod
+from repro.serving.decode import DecodeStream
 from repro.serving.engine import S2M3Engine
+from repro.serving.sampler import select_token
 from repro.serving.scheduler import SchedulerConfig, lm_scheduler
 
 
@@ -91,6 +94,143 @@ def test_generative_results_stream_as_they_finish(tinyllama):
     sched.serve(reqs)
     # shorter decodes finish (and stream) first, not in admission order
     assert order == [1, 2, 0]
+
+
+def _recording_stream(tinyllama, rows=4):
+    """A fresh decode stream over tinyllama whose engine keeps every
+    prefill's logits by prompt and every decode step's logits with the
+    rid each row served."""
+    cfg, bundle, params = tinyllama
+    engine = lm_scheduler(bundle, params).engine
+    stream = DecodeStream(engine, cfg.name, rows=rows, n_pages=33,
+                          page_size=8, max_seq_len=64)
+    prefills, steps = {}, []
+    real_prefill = engine.apply_prefill
+    real_decode = engine.apply_paged_decode
+
+    def prefill(module, batch, cache):
+        logits, cache = real_prefill(module, batch, cache)
+        prefills[tuple(np.asarray(batch["tokens"])[0].tolist())] = logits
+        return logits, cache
+
+    def decode(module, tokens, cache, tables, lengths):
+        logits, cache = real_decode(module, tokens, cache, tables, lengths)
+        steps.append(({row: seq.rid for row, seq in stream.live.items()},
+                      logits))
+        return logits, cache
+
+    engine.apply_prefill = prefill
+    engine.apply_paged_decode = decode
+    return stream, prefills, steps
+
+
+def _serve(stream, reqs):
+    for q in reqs:
+        stream.submit(q.rid, q, {})
+    out = {}
+    while stream.depth():
+        out.update({s.rid: s.tokens for s in stream.tick().finished})
+    return out
+
+
+def _per_row_tokens(reqs, prefills, steps, shift=0):
+    """Each request's tokens as the per-row path picks them from the
+    recorded logits: greedy rows by argmax, sampling rows with a key
+    made from the rid and split once per token."""
+    temps = {q.rid: q.temperature for q in reqs}
+    keys = {q.rid: jax.random.PRNGKey(q.rid) for q in reqs}
+
+    def pick(rid, logits):
+        if temps[rid] <= 0:
+            tok = int(jnp.argmax(logits))
+        else:
+            keys[rid], k = jax.random.split(keys[rid])
+            tok = int(select_token(logits, k, temperature=temps[rid]))
+        return (tok + shift) % logits.shape[-1]
+
+    out = {q.rid: [pick(q.rid, prefills[q.prompt][0])] for q in reqs}
+    for rows, logits in steps:
+        for row, rid in sorted(rows.items()):
+            out[rid].append(pick(rid, logits[row]))
+    return out
+
+
+def test_mixed_temperature_tick_matches_per_row_path(tinyllama):
+    """Greedy rows take the batched pick, sampling rows their own key
+    sequence; every token equals what the per-row path picks from the
+    same step's logits."""
+    stream, prefills, steps = _recording_stream(tinyllama)
+    reqs = [Request(rid=i, model="lm", source="dev0", prompt=(i + 1, 2),
+                    max_new_tokens=5, temperature=t)
+            for i, t in enumerate((0.0, 1.0, 0.0, 0.7))]
+    served = _serve(stream, reqs)
+    assert any({reqs[rid].temperature > 0 for rid in rows.values()}
+               == {False, True} for rows, _ in steps)  # both kinds in a tick
+    assert served == _per_row_tokens(reqs, prefills, steps)
+    # batched picks count exactly the greedy rows' tick tokens
+    assert stream.metrics.total("decode.batched_picks") == 2 * 4
+    assert stream.metrics.total("decode.tokens") == 4 * 4
+
+
+def test_greedy_tick_is_one_pick_call_and_one_host_copy(tinyllama,
+                                                        monkeypatch):
+    stream, _, _ = _recording_stream(tinyllama)
+    reqs = [Request(rid=i, model="lm", source="dev0", prompt=(i + 1,),
+                    max_new_tokens=6) for i in range(3)]
+    for q in reqs:
+        stream.submit(q.rid, q, {})
+    stream.tick()                            # admits all three rows
+    assert len(stream.live) == 3
+
+    calls = {"pick": 0, "copies": 0, "eager": 0, "split": 0}
+    real_pick, real_split = stream._pick, jax.random.split
+
+    def pick(logits):
+        calls["pick"] += 1
+        return real_pick(logits)
+
+    class CountingNp:
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        def asarray(self, a, *args, **kwargs):
+            calls["copies"] += isinstance(a, jax.Array)
+            return np.asarray(a, *args, **kwargs)
+
+    def eager(*a, **k):
+        calls["eager"] += 1
+        return select_token(*a, **k)
+
+    def split(*a, **k):
+        calls["split"] += 1
+        return real_split(*a, **k)
+
+    monkeypatch.setattr(stream, "_pick", pick)
+    monkeypatch.setattr(decode_mod, "np", CountingNp())
+    monkeypatch.setattr(decode_mod, "select_token", eager)
+    monkeypatch.setattr(jax.random, "split", split)
+    report = stream.tick()
+    assert report.decode_batch == 3
+    assert calls == {"pick": 1, "copies": 1, "eager": 0, "split": 0}
+    assert stream.metrics.total("decode.batched_picks") == 2 * 3
+
+
+def test_patched_select_token_reaches_every_token(tinyllama, monkeypatch):
+    """A ``select_token`` patched on ``repro.serving.decode`` before a
+    stream is built picks every token the stream serves, on the batched
+    greedy path and the per-row sampling path alike."""
+
+    def shifted(logits, *a, **k):
+        return (select_token(logits, *a, **k) + 1) % logits.shape[-1]
+
+    monkeypatch.setattr(decode_mod, "select_token", shifted)
+    stream, prefills, steps = _recording_stream(tinyllama)
+    reqs = [Request(rid=i, model="lm", source="dev0", prompt=(i + 3,),
+                    max_new_tokens=6, temperature=t)
+            for i, t in enumerate((0.0, 0.0, 0.8))]
+    served = _serve(stream, reqs)
+    assert all(len(toks) == 6 for toks in served.values())
+    assert served == _per_row_tokens(reqs, prefills, steps, shift=1)
 
 
 def test_vlm_captioning_through_scheduler():
