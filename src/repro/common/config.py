@@ -33,10 +33,17 @@ class ArchConfig:
     first_dense_layers: int = 0      # leading dense blocks (deepseek: 3)
     expert_pad_to: int = 0           # pad expert count for EP divisibility
     router_aux_loss: float = 0.0
+    router_score: str = "softmax"    # softmax | sigmoid (deepseek-v3, kimi)
+    router_bias: bool = False        # score correction for selection only
+    routed_scale: float = 1.0        # gates x routed_scaling_factor
+    # this chip's share of an expert-parallel layer: experts
+    # [first_held_expert, first_held_expert + held_experts); 0 -> all
+    first_held_expert: int = 0
+    held_experts: int = 0
 
     # --- MLA (deepseek) ---
     use_mla: bool = False
-    q_lora_rank: int = 0
+    q_lora_rank: int = 0             # 0 -> one direct q projection
     kv_lora_rank: int = 0
     qk_rope_dim: int = 0
     qk_nope_dim: int = 0

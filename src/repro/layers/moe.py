@@ -15,6 +15,21 @@ Two execution paths sharing one weight layout:
 
 Expert counts that do not divide the model axis are padded with
 zero-initialized, never-routed experts (granite: 40 -> 48).
+
+Routing scores are a softmax over the router's outputs (default) or,
+DeepSeek-V3 style, per-expert sigmoids with a score-correction bias that
+moves selection only (``noaux_tc``); the top-k gates are normalised and
+scaled by ``routed_scale``.  The router runs in float32 at
+``Precision.HIGHEST``, as the published gate does.
+
+A layer may hold only a share of the experts (``first_held_expert``,
+``held_experts``): one chip of an expert-parallel deployment.  It still
+routes over every expert and returns its own experts' part of the
+result, plus the shared experts; on one chip the dense path serves it,
+every held expert on every token masked by the gates (no capacity, no
+drops).  ``moe_apply_dense(..., valid=...)`` also counts the tokens
+routed to each held expert.  The ``ep`` path serves neither a share nor
+a selection bias and falls back to ``dense`` for them.
 """
 
 from __future__ import annotations
@@ -35,8 +50,13 @@ def padded_experts(cfg) -> int:
     return cfg.expert_pad_to or cfg.n_experts
 
 
+def held_experts(cfg) -> int:
+    """Experts whose weights this layer holds."""
+    return cfg.held_experts or padded_experts(cfg)
+
+
 def moe_specs(cfg):
-    E = padded_experts(cfg)
+    E = held_experts(cfg)
     f = cfg.moe_d_ff or cfg.d_ff
     specs = {
         "router": WSpec((cfg.d_model, cfg.n_experts), (None, None), init="small"),
@@ -44,18 +64,32 @@ def moe_specs(cfg):
         "wi_up": WSpec((E, cfg.d_model, f), ("experts", "embed", "expert_mlp")),
         "wo": WSpec((E, f, cfg.d_model), ("experts", "expert_mlp", "embed")),
     }
+    if cfg.router_bias:
+        specs["router_bias"] = WSpec((cfg.n_experts,), (None,), init="zeros")
     if cfg.n_shared_experts:
         specs["shared"] = mlp_specs(cfg.d_model, f * cfg.n_shared_experts)
     return specs
 
 
-def _route(tokens, router, cfg):
-    """tokens: (T, D) -> (gates (T,k), idx (T,k), aux_loss scalar)."""
+def _route(tokens, router, cfg, bias=None):
+    """tokens: (T, D) -> (gates (T,k), idx (T,k), aux_loss scalar).
+    ``bias`` (n_experts,) is added to the scores for selection only."""
     logits = jnp.einsum("td,de->te", tokens.astype(jnp.float32),
-                        router.astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, cfg.experts_top_k)
+                        router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    if cfg.router_score == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+    if bias is None:
+        gates, idx = jax.lax.top_k(probs, cfg.experts_top_k)
+    else:
+        _, idx = jax.lax.top_k(probs + bias.astype(jnp.float32),
+                               cfg.experts_top_k)
+        gates = jnp.take_along_axis(probs, idx, axis=-1)
     gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if cfg.routed_scale != 1.0:
+        gates = gates * cfg.routed_scale
     # load-balancing aux loss (Switch-style)
     frac = jnp.mean(
         jax.nn.one_hot(idx, cfg.n_experts, dtype=jnp.float32), axis=(0, 1)
@@ -65,16 +99,19 @@ def _route(tokens, router, cfg):
     return gates, idx, aux
 
 
-def moe_apply_dense(params, x, cfg):
-    """Oracle path: run all experts, combine with top-k gate weights."""
+def moe_apply_dense(params, x, cfg, valid=None):
+    """One-chip path: run every held expert on every token, combine with
+    the top-k gates of the held experts (a token routed elsewhere gets
+    nothing from them).  With ``valid`` (B, S) also returns the number of
+    valid tokens routed to each held expert, (E_held,) int32."""
     B, S, D = x.shape
-    E = padded_experts(cfg)
+    E = held_experts(cfg)
     tokens = x.reshape(-1, D)
-    gates, idx, aux = _route(tokens, params["router"], cfg)
-    comb = jnp.zeros((tokens.shape[0], E), jnp.float32)
-    comb = jnp.sum(
-        jax.nn.one_hot(idx, E, dtype=jnp.float32) * gates[..., None], axis=1
-    )
+    gates, idx, aux = _route(tokens, params["router"], cfg,
+                             params.get("router_bias"))
+    # one_hot of an expert outside [first, first + E) is all zeros
+    picks = jax.nn.one_hot(idx - cfg.first_held_expert, E, dtype=jnp.float32)
+    comb = jnp.sum(picks * gates[..., None], axis=1)
     act = activation(cfg.act_fn)
     h_g = jnp.einsum("td,edf->etf", tokens, params["wi_gate"].astype(x.dtype))
     h_u = jnp.einsum("td,edf->etf", tokens, params["wi_up"].astype(x.dtype))
@@ -84,7 +121,10 @@ def moe_apply_dense(params, x, cfg):
     y = y.reshape(B, S, D)
     if cfg.n_shared_experts:
         y = y + mlp_apply(params["shared"], x, cfg.act_fn)
-    return y, aux
+    if valid is None:
+        return y, aux
+    w = valid.reshape(-1, 1, 1).astype(jnp.float32)
+    return y, aux, jnp.sum(picks * w, axis=(0, 1)).astype(jnp.int32)
 
 
 def _dp_axes(mesh, batch: int) -> tuple[str, ...]:
@@ -104,7 +144,8 @@ def moe_apply_ep(params, x, cfg, mesh, *, capacity_factor: float = 1.25,
     B, S, D = x.shape
     E = padded_experts(cfg)
     k = cfg.experts_top_k
-    if ep_axis not in mesh.shape or E % mesh.shape[ep_axis] != 0:
+    if (ep_axis not in mesh.shape or E % mesh.shape[ep_axis] != 0
+            or cfg.held_experts or cfg.router_bias):
         return moe_apply_dense(params, x, cfg)
     ep_size = mesh.shape[ep_axis]
     E_loc = E // ep_size

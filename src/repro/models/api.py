@@ -45,16 +45,21 @@ class ModelBundle:
     cfg: ArchConfig
     specs: Any                       # weights WSpec tree
     loss_fn: Callable                # (params, batch) -> (loss, metrics)
-    prefill: Callable                # (params, batch, cache) -> (logits_last, cache)
+    # prefill and paged_decode_step also return ``counts``: the valid
+    # tokens routed to each held expert per MoE layer, (n_moe_layers,
+    # E_held) int32, where routed stages run on one device; (0, 0) else
+    prefill: Callable                # (params, batch, cache) -> (logits_last, cache, counts)
     decode_step: Callable            # (params, tokens, cache, lengths) -> (logits, cache)
     cache_specs: Callable            # (B, T) -> WSpec tree
     batch_specs: Callable            # (ShapeConfig) -> WSpec tree
     mesh: Any = None
     rules: Any = None
-    # paged-KV decode (serving substrate); None for cache families the
-    # page layout doesn't cover (state-space / MLA / enc-dec caches)
+    # paged-KV decode (serving substrate): present where every leaf of
+    # every stage cache has a token axis (attention {k, v}, MLA's latent
+    # {ckv, kr}); None where a stage carries recurrent state (mamba,
+    # xLSTM) and for enc-dec caches
     paged_decode_step: Callable | None = None   # (params, tokens, cache,
-    #                                              block_tables, lengths)
+    #                          block_tables, lengths) -> (logits, cache, counts)
     paged_cache_specs: Callable | None = None   # (n_pages, page_size, dtype)
 
     def init(self, key, param_dtype=jnp.float32):
@@ -78,7 +83,7 @@ class ModelBundle:
         if self.paged_cache_specs is None:
             raise NotImplementedError(
                 f"family {self.cfg.family!r} has no paged-KV cache layout "
-                "(only pure-attention caches page)")
+                "(only caches with a token axis page)")
         return init_tree(jax.random.PRNGKey(0),
                          self.paged_cache_specs(n_pages, page_size, dtype))
 
@@ -135,9 +140,13 @@ def _make_ctx(cfg, mesh, rules, mode, positions, lengths, opts):
 
 
 def _run_backbone(stages, params, h, ctx, caches):
-    """Run all stages; returns (h, aux_loss, new_caches)."""
+    """Run all stages; returns (h, aux_loss, new_caches, counts).  Where
+    ``ctx["token_valid"]`` is set, routed stages also give the tokens
+    routed to each held expert, and ``counts`` is those of every routed
+    layer in order, (n_routed_layers, E_held); else (0, 0)."""
     carry = (h, jnp.zeros((), F32))
     new_caches = {}
+    counts = []
     for st in stages:
         p_st = params["stages"][st.name]
         ctx_st = dict(ctx)
@@ -154,9 +163,14 @@ def _run_backbone(stages, params, h, ctx, caches):
             fn, p_st["blocks"], carry, xs=cache_st, remat=ctx["remat"],
             unroll=ctx.get("unroll", False),
         )
+        if st.routed and ctx.get("token_valid") is not None:
+            ys, c = ys
+            counts.append(c)
         if caches is not None:
             new_caches[st.name] = ys
-    return carry[0], carry[1], new_caches
+    return (carry[0], carry[1], new_caches,
+            jnp.concatenate(counts) if counts
+            else jnp.zeros((0, 0), jnp.int32))
 
 
 def _lm_specs(cfg, stages):
@@ -271,7 +285,7 @@ def build_model(cfg: ArchConfig, mesh=None, rules=None, **opts) -> ModelBundle:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         ctx = _make_ctx(cfg, mesh, rules, "train", positions, None, opts)
         h = ctx["constrain"](h)
-        h, aux, _ = _run_backbone(stages, params, h, ctx, None)
+        h, aux, _, _ = _run_backbone(stages, params, h, ctx, None)
         h = apply_norm(params["final_norm"], h, cfg.norm, cfg.norm_eps)
         if n_prefix:
             h = h[:, n_prefix:]
@@ -290,6 +304,10 @@ def build_model(cfg: ArchConfig, mesh=None, rules=None, **opts) -> ModelBundle:
         metrics["loss"] = loss
         return loss, metrics
 
+    # routed stages on one device count their held experts' tokens in
+    # prefill and paged decode
+    count_experts = mesh is None and any(st.routed for st in stages)
+
     # ---- prefill ----
     def prefill(params, batch, cache):
         h, n_prefix = _embed_inputs(cfg, params, batch, compute_dtype)
@@ -299,13 +317,15 @@ def build_model(cfg: ArchConfig, mesh=None, rules=None, **opts) -> ModelBundle:
         if lengths is None:
             lengths = jnp.full((B,), S, jnp.int32)
         ctx = _make_ctx(cfg, mesh, rules, "prefill", positions, lengths, opts)
+        if count_experts:
+            ctx["token_valid"] = positions < lengths[:, None]
         h = ctx["constrain"](h)
-        h, _, new_caches = _run_backbone(stages, params, h, ctx, cache)
+        h, _, new_caches, counts = _run_backbone(stages, params, h, ctx, cache)
         h = apply_norm(params["final_norm"], h, cfg.norm, cfg.norm_eps)
         last = jnp.clip(lengths - 1, 0, S - 1)
         h_last = h[jnp.arange(B), last][:, None, :]
         logits = _logits(cfg, params, h_last)[:, 0]
-        return logits, new_caches
+        return logits, new_caches, counts
 
     # ---- decode ----
     def decode_step(params, tokens, cache, lengths):
@@ -316,18 +336,18 @@ def build_model(cfg: ArchConfig, mesh=None, rules=None, **opts) -> ModelBundle:
         B = h.shape[0]
         positions = lengths[:, None].astype(jnp.int32)
         ctx = _make_ctx(cfg, mesh, rules, "decode", positions, lengths, opts)
-        h, _, new_caches = _run_backbone(stages, params, h, ctx, cache)
+        h, _, new_caches, _ = _run_backbone(stages, params, h, ctx, cache)
         h = apply_norm(params["final_norm"], h, cfg.norm, cfg.norm_eps)
         logits = _logits(cfg, params, h)[:, 0]
         return logits, new_caches
 
     # ---- paged decode (serving substrate) ----
-    # Every (dense/vlm) stage cache is a {"k","v"} pytree whose leaves
-    # are (B, T, K, D): re-parameterizing (B, T) as (n_pages, page_size)
-    # yields the global page pool the batched paged decode kernel and
-    # block-table scatter consume.  State-space / MLA / enc-dec caches
-    # don't fit the page layout; those bundles keep the fields None.
-    paged_supported = cfg.family in ("dense", "vlm")
+    # A stage cache pages when every leaf has a token axis, (B, T, ...):
+    # attention's {"k","v"} and MLA's latent {"ckv","kr"}.  Re-
+    # parameterizing (B, T) as (n_pages, page_size) yields the global
+    # page pool the block-table scatter and gather consume.  Recurrent
+    # state (mamba, xLSTM) has no token axis; those bundles keep the
+    # fields None (``paged_supported`` below).
 
     def paged_decode_step(params, tokens, cache, block_tables, lengths):
         h = embed_apply(
@@ -338,10 +358,13 @@ def build_model(cfg: ArchConfig, mesh=None, rules=None, **opts) -> ModelBundle:
         ctx = _make_ctx(cfg, mesh, rules, "decode", positions, lengths, opts)
         ctx["cache_layout"] = "paged"
         ctx["block_tables"] = block_tables
-        h, _, new_caches = _run_backbone(stages, params, h, ctx, cache)
+        if count_experts:
+            # dead rows hold length 0; a live row's cache is never empty
+            ctx["token_valid"] = (lengths > 0)[:, None]
+        h, _, new_caches, counts = _run_backbone(stages, params, h, ctx, cache)
         h = apply_norm(params["final_norm"], h, cfg.norm, cfg.norm_eps)
         logits = _logits(cfg, params, h)[:, 0]
-        return logits, new_caches
+        return logits, new_caches, counts
 
     def paged_cache_specs(n_pages, page_size, dtype=jnp.bfloat16):
         return cache_specs(n_pages, page_size, dtype)
@@ -386,6 +409,10 @@ def build_model(cfg: ArchConfig, mesh=None, rules=None, **opts) -> ModelBundle:
             "tokens": WSpec((B, 1), ("batch", None), dtype=jnp.int32),
             "lengths": WSpec((B,), ("batch",), dtype=jnp.int32),
         }
+
+    paged_supported = all(
+        "cache_seq" in ws.axes
+        for ws in jax.tree.leaves(cache_specs(1, 1), is_leaf=_is_ws))
 
     return ModelBundle(
         cfg=cfg, specs=specs, loss_fn=loss_fn, prefill=prefill,

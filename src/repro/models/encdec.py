@@ -227,7 +227,8 @@ def build_encdec(cfg, mesh=None, rules=None, **opts):
         h = apply_norm(params["final_norm"], h, cfg.norm, cfg.norm_eps)
         last = jnp.clip(lengths - 1, 0, S - 1)
         logits = _head(params, h[jnp.arange(B), last][:, None])[:, 0]
-        return logits, new_cache
+        # no routed experts: the bundle's empty per-expert counts
+        return logits, new_cache, jnp.zeros((0, 0), jnp.int32)
 
     def decode_step(params, tokens, cache, lengths):
         B = tokens.shape[0]

@@ -215,9 +215,16 @@ def _paged_attn_decode(p, h, x, cache, q, k_new, v_new, ctx, cfg, *,
 
 
 def _apply_ffn_sub(p, h, ctx, cfg, *, use_moe: bool, post_norm: bool):
+    """-> (h, aux, counts): ``counts`` (E_held,) is the number of valid
+    tokens routed to each held expert where ``ctx["token_valid"]`` asks
+    for it (prefill and paged decode of a routed stage), else None."""
     x = apply_norm(p["ln_mlp"], h, cfg.norm, cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
-    if use_moe:
+    counts = None
+    if use_moe and ctx.get("token_valid") is not None:
+        y, aux, counts = moe_lib.moe_apply_dense(
+            p["moe"], x, cfg, valid=ctx["token_valid"])
+    elif use_moe:
         y, aux = moe_lib.moe_apply(
             p["moe"], x, cfg, mesh=ctx["mesh"], impl=ctx["moe_impl"]
         )
@@ -225,7 +232,12 @@ def _apply_ffn_sub(p, h, ctx, cfg, *, use_moe: bool, post_norm: bool):
         y = mlp_apply(p["mlp"], x, cfg.act_fn)
     if post_norm:
         y = apply_norm(p["ln_mlp_post"], y, cfg.norm, cfg.norm_eps)
-    return h + y, aux
+    return h + y, aux, counts
+
+
+def _with_counts(new_cache, counts):
+    """A counting block's scan output: (cache, counts)."""
+    return new_cache if counts is None else (new_cache, counts)
 
 
 def _attn_block(p, carry, cache, ctx, cfg, *, local: bool, use_moe: bool,
@@ -234,8 +246,9 @@ def _attn_block(p, carry, cache, ctx, cfg, *, local: bool, use_moe: bool,
     h = ctx["constrain"](h)
     h, new_cache = _apply_attn_sub(p, h, cache, ctx, cfg, local=local,
                                    post_norm=post_norm)
-    h, aux = _apply_ffn_sub(p, h, ctx, cfg, use_moe=use_moe, post_norm=post_norm)
-    return (h, aux_acc + aux), new_cache
+    h, aux, counts = _apply_ffn_sub(p, h, ctx, cfg, use_moe=use_moe,
+                                    post_norm=post_norm)
+    return (h, aux_acc + aux), _with_counts(new_cache, counts)
 
 
 def _mla_block(p, carry, cache, ctx, cfg, *, use_moe: bool):
@@ -253,6 +266,23 @@ def _mla_block(p, carry, cache, ctx, cfg, *, use_moe: bool):
             "ckv": cache["ckv"].at[:, :S].set(ckv.astype(cache["ckv"].dtype)),
             "kr": cache["kr"].at[:, :S].set(kr.astype(cache["kr"].dtype)),
         }
+    elif ctx.get("cache_layout") == "paged":
+        # cache leaves are latent page pools (n_pages, page_size, R|r);
+        # attention runs absorbed, in the latent space
+        lengths, tables = ctx["lengths"], ctx["block_tables"]
+        ckv_new, kr_new = mla_lib.mla_project_kv(
+            p["attn"], x, ctx["positions"], cfg)
+        ckv_c = attn.paged_cache_insert(cache["ckv"], ckv_new, tables, lengths)
+        kr_c = attn.paged_cache_insert(cache["kr"], kr_new, tables, lengths)
+        ckv_seq = attn.paged_gather(ckv_c, tables).astype(x.dtype)
+        kr_seq = attn.paged_gather(kr_c, tables).astype(x.dtype)
+        T = ckv_seq.shape[1]
+        kv_valid = jnp.arange(T, dtype=jnp.int32)[None, :] < (lengths + 1)[:, None]
+        y = mla_lib.mla_attend_absorbed(
+            p["attn"], x, positions=ctx["positions"], cfg=cfg,
+            ckv_all=ckv_seq, kr_all=kr_seq, kv_valid=kv_valid,
+        )
+        new_cache = {"ckv": ckv_c, "kr": kr_c}
     else:
         lengths = ctx["lengths"]
         ckv_new, kr_new = mla_lib.mla_project_kv(
@@ -272,8 +302,9 @@ def _mla_block(p, carry, cache, ctx, cfg, *, use_moe: bool):
         )
         new_cache = {"ckv": ckv_c, "kr": kr_c}
     h = h + y
-    h, aux = _apply_ffn_sub(p, h, ctx, cfg, use_moe=use_moe, post_norm=False)
-    return (h, aux_acc + aux), new_cache
+    h, aux, counts = _apply_ffn_sub(p, h, ctx, cfg, use_moe=use_moe,
+                                    post_norm=False)
+    return (h, aux_acc + aux), _with_counts(new_cache, counts)
 
 
 def _mamba_block(p, carry, cache, ctx, cfg):
@@ -316,6 +347,7 @@ class StageDef:
     block_fn: Callable                       # (p, carry, cache_l, ctx) -> ((h,aux), cache_l')
     cache_specs: Callable | None             # (cfg, B, T, dtype) -> per-layer WSpec tree
     shared_specs: Any = None                 # non-scanned weights (zamba shared attn)
+    routed: bool = False                     # blocks route tokens to experts
 
 
 def _kv_cache_specs(cfg, B, T, dtype):
@@ -427,13 +459,14 @@ def make_stages(cfg) -> list[StageDef]:
                 "moe", cfg.n_layers - cfg.first_dense_layers,
                 _mla_block_specs(cfg, True),
                 partial(_mla_block, cfg=cfg, use_moe=True), _mla_cache_specs,
+                routed=True,
             ))
         else:
             stages.append(StageDef(
                 "moe", cfg.n_layers, _attn_block_specs(cfg, True, cfg.post_norm),
                 partial(_attn_block, cfg=cfg, local=False, use_moe=True,
                         post_norm=cfg.post_norm),
-                _kv_cache_specs,
+                _kv_cache_specs, routed=True,
             ))
 
     elif fam == "hybrid":  # zamba2: superblocks of mamba + shared attention

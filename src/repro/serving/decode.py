@@ -118,6 +118,12 @@ class DecodeStream:
         # select_token is looked up now, so a patched one is traced
         self._pick = jax.jit(partial(select_token, rng=None,
                                      temperature=0.0))
+        # a decoder with routed experts also gives, per step, the tokens
+        # each (MoE layer, held expert) took (``rt.expert_counts``); they
+        # come to the host in the pick's copy, after the picked tokens
+        pick = self._pick
+        self._pick_counts = jax.jit(lambda logits, counts: jnp.concatenate(
+            [pick(logits), counts.reshape(-1)]))
 
     # legacy counter attributes, now views over the metrics registry
     @property
@@ -234,9 +240,13 @@ class DecodeStream:
         t0 = self._now()
         batch = self.engine.gen_batch(req.prompt, seq.enc_outputs)
         logits, one = self.engine.apply_prefill(self.module, batch, one)
+        counts = self.rt.expert_counts
         self.cache = insert_pages(self.cache, one, pages, seq.length)
+        tags = {}
+        if counts.size or req.temperature <= 0:
+            picked, _, tags = self._picked(logits, counts)
         if req.temperature <= 0:
-            tok = int(np.asarray(self._pick(logits))[0])
+            tok = int(picked[0])
         else:
             seq.rng = jax.random.PRNGKey((seq.rid or 0) & 0x7FFFFFFF)
             seq.rng, k = jax.random.split(seq.rng)
@@ -246,7 +256,7 @@ class DecodeStream:
         span = self.tracer.record(self.module, "prefill", t0, self._now(),
                                   rid=seq.rid, parent=seq.parent,
                                   prompt_tokens=len(req.prompt),
-                                  prefix_len=seq.length)
+                                  prefix_len=seq.length, **tags)
         seq.timeline.append(span)
         self._c_prefills.inc()
 
@@ -320,11 +330,14 @@ class DecodeStream:
         logits, cache = self.engine.apply_paged_decode(
             self.module, jnp.asarray(tokens), self.cache,
             jnp.asarray(tables), jnp.asarray(lengths))
+        counts = self.rt.expert_counts
         t_sample = self._now()
         self.cache = cache
         greedy = {row for row, seq in live
                   if seq.request.temperature <= 0}
-        picked = np.asarray(self._pick(logits)) if greedy else None
+        picked, routed, tags = (self._picked(logits, counts)
+                                if greedy or counts.size
+                                else (None, None, {}))
         picks: dict[int, int] = {}
         for row, seq in live:
             if row in greedy:
@@ -337,10 +350,13 @@ class DecodeStream:
         for row, seq in live:
             self.tracer.record(self.module, "decode_tick", t0, t1,
                                rid=seq.rid, parent=seq.decode_sid,
-                               rows=len(live), pages_live=pages_live)
+                               rows=len(live), pages_live=pages_live,
+                               **tags)
         finished = []
         with self._lock:
             self._c_batched.inc(len(greedy))
+            if routed is not None:
+                self._count_experts(routed)
             for row, seq in live:
                 seq.length += 1
                 self.lengths[row] = seq.length
@@ -361,6 +377,31 @@ class DecodeStream:
             self.tracer.record(self.module, phase, a, b, rid=first.rid,
                                parent=first.parent, rows=len(live))
         return finished, len(live)
+
+    def _picked(self, logits, counts):
+        """Every row's greedy pick and, for a decoder with routed
+        experts (``counts`` not empty), the tokens per (MoE layer, held
+        expert) and the span tags they give, in one host copy:
+        (picked, counts or None, tags)."""
+        if not counts.size:
+            return np.asarray(self._pick(logits)), None, {}
+        got = np.asarray(self._pick_counts(logits, counts))
+        rows = logits.shape[0]
+        n = got[rows:].reshape(counts.shape)
+        return got[:rows], n, {"experts_touched": int((n > 0).sum()),
+                               "local_pairs": int(n.sum())}
+
+    def _count_experts(self, counts) -> None:
+        """One decode tick's picks that land on held experts
+        (``moe.local_pairs``) and each held expert's tokens summed over
+        the MoE layers (``moe.expert_tokens{expert}``)."""
+        self.metrics.counter("moe.local_pairs", module=self.module).inc(
+            int(counts.sum()))
+        first = self.rt.bundle.cfg.first_held_expert
+        for e, n in enumerate(counts.sum(axis=0).tolist()):
+            if n:
+                self.metrics.counter("moe.expert_tokens", module=self.module,
+                                     expert=first + e).inc(n)
 
     def tick(self) -> TickReport:
         """One scheduler service round: admit what fits, then one

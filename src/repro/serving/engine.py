@@ -64,6 +64,9 @@ class DecoderRuntime:
     prefill_jit: Callable = None
     paged_decode_jit: Callable = None
     decode_jit: Callable = None
+    # the last prefill's or paged decode step's tokens per (MoE layer,
+    # held expert), (0, 0) for a decoder without routed experts
+    expert_counts: Any = None
 
     @property
     def n_prefix(self) -> int:
@@ -304,23 +307,29 @@ class S2M3Engine:
     def apply_prefill(self, module_name: str, batch: dict[str, Any],
                       cache) -> tuple[Any, Any]:
         """Batch-1 prefill on the decoder's pinned host; returns
-        (last-token logits, filled dense cache)."""
+        (last-token logits, filled dense cache) and keeps the tokens per
+        (MoE layer, held expert) in ``rt.expert_counts``."""
         rt = self.decoder_runtime(module_name)
         batch = {k: jax.device_put(v, rt.device) for k, v in batch.items()}
-        return rt.prefill_jit(rt.params, batch, cache)
+        logits, cache, rt.expert_counts = rt.prefill_jit(rt.params, batch,
+                                                         cache)
+        return logits, cache
 
     def apply_paged_decode(self, module_name: str, tokens, cache,
                            block_tables, lengths) -> tuple[Any, Any]:
-        """One batched decode step over the paged KV cache.  The cache
-        argument is donated — callers must rebind to the returned cache
-        and never reuse the old reference."""
+        """One batched decode step over the paged KV cache: (logits,
+        cache); the live rows' tokens per (MoE layer, held expert) are
+        kept in ``rt.expert_counts``.  The cache argument is donated —
+        callers must rebind to the returned cache and never reuse the
+        old reference."""
         rt = self.decoder_runtime(module_name)
         if rt.paged_decode_jit is None:
             raise NotImplementedError(
                 f"decoder {module_name!r} (family "
                 f"{rt.bundle.cfg.family!r}) has no paged decode path")
-        return rt.paged_decode_jit(rt.params, tokens, cache,
-                                   block_tables, lengths)
+        logits, cache, rt.expert_counts = rt.paged_decode_jit(
+            rt.params, tokens, cache, block_tables, lengths)
+        return logits, cache
 
     def generate(self, request) -> InferenceResult:
         """Solo generative inference: encoders run as in ``infer()``;

@@ -93,13 +93,13 @@ def test_decode_matches_prefill(arch):
     n_pref = cfg.n_image_tokens if cfg.has_vision_stub else 0
 
     cache = m.init_cache(B, T, dtype=jnp.float32)
-    _, cache = jax.jit(m.prefill)(params, {"tokens": toks[:, :S], **extra},
-                                  cache)
+    _, cache, _ = jax.jit(m.prefill)(params, {"tokens": toks[:, :S], **extra},
+                                     cache)
     lengths = jnp.full((B,), S + n_pref, jnp.int32)
     logits, _ = jax.jit(m.decode_step)(params, toks[:, S:], cache, lengths)
 
     cache2 = m.init_cache(B, T, dtype=jnp.float32)
-    logits_ref, _ = jax.jit(m.prefill)(
+    logits_ref, _, _ = jax.jit(m.prefill)(
         params, {"tokens": toks[:, : S + 1], **extra}, cache2)
     np.testing.assert_allclose(np.asarray(logits), np.asarray(logits_ref),
                                rtol=5e-4, atol=5e-4)

@@ -24,7 +24,7 @@ from repro.serving.scheduler import SchedulerConfig, lm_scheduler
 def _reference_generate(bundle, params, prompt, n_new, cache_len=64):
     """Sequential greedy decoding oracle (dense contiguous cache)."""
     cache = bundle.init_cache(1, cache_len, dtype=jnp.float32)
-    logits, cache = jax.jit(bundle.prefill)(
+    logits, cache, _ = jax.jit(bundle.prefill)(
         params, {"tokens": jnp.asarray([prompt], jnp.int32)}, cache)
     out = [int(jnp.argmax(logits[0]))]
     length = len(prompt)
