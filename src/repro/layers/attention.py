@@ -268,31 +268,61 @@ def cache_insert(cache_arr, new_val, lengths, *, mode: str = "scatter",
         new_val[:, 0].astype(cache_arr.dtype))
 
 
-def paged_cache_insert(pages, new_val, block_tables, lengths):
-    """Insert new_val (B, 1, ...) into a paged cache (n_pages,
-    page_size, ...) at per-sequence position ``lengths``, resolving the
-    owning page through ``block_tables`` (B, n_max).
+LANES = 128   # minor dimension of a TPU vector tile (8 x 128 float32)
+
+
+def pool_row_width(row: int) -> int:
+    """Width of a page-pool leaf's minor axis for a token row of ``row``
+    elements: whole lane tiles.  The TPU compiler keeps a pool carried
+    through the decode layer loop in place only when a token's row is a
+    multiple of 128 lanes; otherwise it gives the pool a pages-minor
+    layout and relayouts the whole stack every step."""
+    return -(-row // LANES) * LANES
+
+
+def to_pool_rows(x, n_lead: int, width: int):
+    """The page pool's token-row layout: ``x``'s axes after the first
+    ``n_lead`` flattened into one, zero-padded to ``width`` lanes."""
+    x = x.reshape(*x.shape[:n_lead], -1)
+    return jnp.pad(x, ((0, 0),) * n_lead + ((0, width - x.shape[-1]),))
+
+
+def from_pool_rows(x, row_shape):
+    """Undo ``to_pool_rows``: drop the padding lanes of the last axis
+    and restore each token's ``row_shape``."""
+    return x[..., :math.prod(row_shape)].reshape(*x.shape[:-1], *row_shape)
+
+
+def paged_cache_insert(pool, layer, new_val, block_tables, lengths):
+    """Write new_val (B, 1, ...) into layer ``layer`` of a stacked page
+    pool (layers, n_pages, page_size, W) at per-sequence position
+    ``lengths``, resolving the owning page through ``block_tables`` (B,
+    n_max).  Each row is flattened and zero-padded to the pool's W lanes
+    and scattered into the stacked pool itself, which is updated in
+    place where it is donated.
 
     Live sequences never share pages, so the batched scatter indices
     are unique across rows; rows whose table points at a dummy page
     (dead decode rows) collide only with each other, on a page no
     sequence reads.
     """
-    ps = pages.shape[1]
+    ps, W = pool.shape[2], pool.shape[3]
     B = new_val.shape[0]
     n_max = block_tables.shape[1]
     page = block_tables[jnp.arange(B), jnp.clip(lengths // ps, 0, n_max - 1)]
     off = lengths % ps
-    return pages.at[page, off].set(new_val[:, 0].astype(pages.dtype))
+    row = to_pool_rows(new_val.astype(pool.dtype), 1, W)
+    return pool.at[layer, page, off].set(row)
 
 
-def paged_gather(pages, block_tables):
-    """Materialize each sequence's pages contiguously: (n_pages, ps,
-    ...) + tables (B, n_max) -> (B, n_max*ps, ...) — the XLA-path view
-    the paged Pallas kernel avoids building."""
+def paged_gather(pool, layer, block_tables, row_shape):
+    """Materialize each sequence's pages of layer ``layer`` contiguously,
+    in one gather straight from the stacked pool (layers, n_pages, ps,
+    W) + tables (B, n_max) -> (B, n_max*ps, *row_shape) — the XLA-path
+    view the paged Pallas kernel avoids building."""
     B, n_max = block_tables.shape
-    ps = pages.shape[1]
-    return pages[block_tables].reshape(B, n_max * ps, *pages.shape[2:])
+    seq = pool[layer, block_tables].reshape(B, n_max * pool.shape[2], -1)
+    return from_pool_rows(seq, row_shape)
 
 
 def _cache_insert_shardmap(cache_arr, new_val, lengths, mesh, rules):

@@ -173,6 +173,39 @@ def _run_backbone(stages, params, h, ctx, caches):
             else jnp.zeros((0, 0), jnp.int32))
 
 
+def _run_paged_decode(stages, params, h, ctx, pools):
+    """Paged decode's layer loop; returns (h, new_pools, counts) as
+    ``_run_backbone``.  Each stage's stacked page pool rides in the
+    scan's carry instead of its ``xs``/``ys``: block ``l`` (given as
+    ``ctx["layer"]``) scatters its new row into the stacked pool and
+    gathers its pages straight from it, so a donated pool is updated in
+    place and never sliced, copied or rebuilt per layer."""
+    aux = jnp.zeros((), F32)
+    new_pools = {}
+    counts = []
+    count = ctx.get("token_valid") is not None
+    for st in stages:
+        def body(carry, xs, st=st):
+            h, aux, pool = carry
+            lp, layer = xs
+            (h, aux), out = st.block_fn(lp, (h, aux), pool,
+                                        dict(ctx, layer=layer))
+            c = jnp.zeros((0,), jnp.int32)
+            if st.routed and count:
+                out, c = out
+            return (h, aux, out), c
+
+        (h, aux, new_pools[st.name]), c = jax.lax.scan(
+            body, (h, aux, pools[st.name]),
+            (params["stages"][st.name]["blocks"], jnp.arange(st.n)),
+            unroll=ctx.get("unroll", False))
+        if st.routed and count:
+            counts.append(c)
+    return (h, new_pools,
+            jnp.concatenate(counts) if counts
+            else jnp.zeros((0, 0), jnp.int32))
+
+
 def _lm_specs(cfg, stages):
     sp: dict[str, Any] = {
         "embed": embed_specs(cfg.vocab_size, cfg.d_model),
@@ -344,10 +377,11 @@ def build_model(cfg: ArchConfig, mesh=None, rules=None, **opts) -> ModelBundle:
     # ---- paged decode (serving substrate) ----
     # A stage cache pages when every leaf has a token axis, (B, T, ...):
     # attention's {"k","v"} and MLA's latent {"ckv","kr"}.  Re-
-    # parameterizing (B, T) as (n_pages, page_size) yields the global
-    # page pool the block-table scatter and gather consume.  Recurrent
-    # state (mamba, xLSTM) has no token axis; those bundles keep the
-    # fields None (``paged_supported`` below).
+    # parameterizing (B, T) as (n_pages, page_size), with a token's row
+    # flattened to one lane-padded axis, yields the global page pool the
+    # block-table scatter and gather consume.  Recurrent state (mamba,
+    # xLSTM) has no token axis; those bundles keep the fields None
+    # (``paged_supported`` below).
 
     def paged_decode_step(params, tokens, cache, block_tables, lengths):
         h = embed_apply(
@@ -361,13 +395,23 @@ def build_model(cfg: ArchConfig, mesh=None, rules=None, **opts) -> ModelBundle:
         if count_experts:
             # dead rows hold length 0; a live row's cache is never empty
             ctx["token_valid"] = (lengths > 0)[:, None]
-        h, _, new_caches, counts = _run_backbone(stages, params, h, ctx, cache)
+        h, new_caches, counts = _run_paged_decode(stages, params, h, ctx,
+                                                  cache)
         h = apply_norm(params["final_norm"], h, cfg.norm, cfg.norm_eps)
         logits = _logits(cfg, params, h)[:, 0]
         return logits, new_caches, counts
 
     def paged_cache_specs(n_pages, page_size, dtype=jnp.bfloat16):
-        return cache_specs(n_pages, page_size, dtype)
+        """Every leaf (layers, n_pages, page_size, W): a token's row
+        (attention's K*D, MLA's R or r) on one minor axis, zero-padded
+        to whole TPU lane tiles (``attn_lib.pool_row_width``)."""
+        def page(ws):
+            row = attn_lib.pool_row_width(math.prod(ws.shape[3:]))
+            return dataclasses.replace(ws, shape=(*ws.shape[:3], row),
+                                       axes=(*ws.axes[:3], None))
+
+        return jax.tree.map(page, cache_specs(n_pages, page_size, dtype),
+                            is_leaf=_is_ws)
 
     # ---- cache / batch specs ----
     def cache_specs(B, T, dtype=jnp.bfloat16):

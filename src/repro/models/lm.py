@@ -174,31 +174,40 @@ def _apply_attn_sub(p, h, cache, ctx, cfg, *, local: bool, post_norm: bool):
 
 def _paged_attn_decode(p, h, x, cache, q, k_new, v_new, ctx, cfg, *,
                        local: bool, post_norm: bool):
-    """Decode step against a paged KV cache: cache leaves are global
-    page pools (n_pages, page_size, K, D); ``ctx["block_tables"]``
-    (B, n_max) names each row's pages.  ``ctx["paged_attn"]`` picks the
-    attention path: "pallas"/"pallas_interpret" run the batched paged
-    kernel; "xla" (default, and any local/windowed layer — the kernel
-    has no window support) gathers the owned pages and reuses
-    gqa_scores."""
+    """Decode step against a paged KV cache: cache leaves are the
+    stage's stacked page pools (layers, n_pages, page_size, W), a
+    token's K*D row padded to W lanes, and this block is layer
+    ``ctx["layer"]``; ``ctx["block_tables"]`` (B, n_max) names each
+    row's pages.  ``ctx["paged_attn"]`` picks the attention path:
+    "pallas"/"pallas_interpret" run the batched paged kernel on this
+    layer's (n_pages, page_size, K, D) view; "xla" (default, and any
+    local/windowed layer — the kernel has no window support) gathers
+    the owned pages and reuses gqa_scores."""
     lengths = ctx["lengths"]
     tables = ctx["block_tables"]
+    layer = ctx["layer"]
     B = x.shape[0]
-    k_cache = attn.paged_cache_insert(cache["k"], k_new, tables, lengths)
-    v_cache = attn.paged_cache_insert(cache["v"], v_new, tables, lengths)
+    k_cache = attn.paged_cache_insert(cache["k"], layer, k_new, tables,
+                                      lengths)
+    v_cache = attn.paged_cache_insert(cache["v"], layer, v_new, tables,
+                                      lengths)
     impl = ctx.get("paged_attn", "xla")
     window = cfg.sliding_window if local else 0
+    row = k_new.shape[2:]
     if impl in ("pallas", "pallas_interpret") and not window:
         from repro.kernels import ops as kops
 
+        def view(pool):
+            return attn.from_pool_rows(pool[layer], row).astype(x.dtype)
+
         out = kops.paged_decode_attention(
-            q[:, 0], k_cache.astype(x.dtype), v_cache.astype(x.dtype),
+            q[:, 0], view(k_cache), view(v_cache),
             tables, lengths + 1,
             softcap=cfg.attn_logit_softcap,
             interpret=(impl == "pallas_interpret"))[:, None]
     else:
-        k_seq = attn.paged_gather(k_cache, tables)
-        v_seq = attn.paged_gather(v_cache, tables)
+        k_seq = attn.paged_gather(k_cache, layer, tables, row)
+        v_seq = attn.paged_gather(v_cache, layer, tables, row)
         T = k_seq.shape[1]
         kv_pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
         kv_valid = kv_pos < (lengths + 1)[:, None]
@@ -267,15 +276,21 @@ def _mla_block(p, carry, cache, ctx, cfg, *, use_moe: bool):
             "kr": cache["kr"].at[:, :S].set(kr.astype(cache["kr"].dtype)),
         }
     elif ctx.get("cache_layout") == "paged":
-        # cache leaves are latent page pools (n_pages, page_size, R|r);
-        # attention runs absorbed, in the latent space
+        # cache leaves are the stage's stacked latent page pools (layers,
+        # n_pages, page_size, W), R or r lanes padded to W; this block is
+        # layer ctx["layer"].  Attention runs absorbed, in the latent space
         lengths, tables = ctx["lengths"], ctx["block_tables"]
+        layer = ctx["layer"]
         ckv_new, kr_new = mla_lib.mla_project_kv(
             p["attn"], x, ctx["positions"], cfg)
-        ckv_c = attn.paged_cache_insert(cache["ckv"], ckv_new, tables, lengths)
-        kr_c = attn.paged_cache_insert(cache["kr"], kr_new, tables, lengths)
-        ckv_seq = attn.paged_gather(ckv_c, tables).astype(x.dtype)
-        kr_seq = attn.paged_gather(kr_c, tables).astype(x.dtype)
+        ckv_c = attn.paged_cache_insert(cache["ckv"], layer, ckv_new, tables,
+                                        lengths)
+        kr_c = attn.paged_cache_insert(cache["kr"], layer, kr_new, tables,
+                                       lengths)
+        ckv_seq = attn.paged_gather(ckv_c, layer, tables,
+                                    ckv_new.shape[2:]).astype(x.dtype)
+        kr_seq = attn.paged_gather(kr_c, layer, tables,
+                                   kr_new.shape[2:]).astype(x.dtype)
         T = ckv_seq.shape[1]
         kv_valid = jnp.arange(T, dtype=jnp.int32)[None, :] < (lengths + 1)[:, None]
         y = mla_lib.mla_attend_absorbed(

@@ -1,7 +1,8 @@
 """Paged KV-cache allocation for continuous batching.
 
 The decode cache is a global pool of fixed-size pages — every
-attention-cache leaf is ``(layers, n_pages, page_size, ...)`` — and
+attention-cache leaf is ``(layers, n_pages, page_size, W)``, a token's
+row on one lane-padded minor axis — and
 ``PagePool`` hands out pages and maintains the per-sequence *block
 tables* that the paged ``decode_attention`` kernel consumes.  Pages are
 recycled LIFO so a hot working set stays small; ``fragmentation()``
@@ -21,6 +22,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.layers.attention import to_pool_rows
 
 
 class PagesExhausted(RuntimeError):
@@ -221,8 +224,9 @@ def insert_sequence(big_cache, one_cache, slot: int):
 def insert_pages(paged_cache, one_cache, page_ids, n_tokens: int):
     """Scatter a batch-1 *dense* prefill cache into the page pool.
 
-    Paged leaves are (layers, n_pages, page_size, ...); dense leaves
-    are (layers, 1, T, ...) with T >= the pages' token span.  The first
+    Paged leaves are (layers, n_pages, page_size, W), a token's row
+    flattened and zero-padded to W lanes; dense leaves are (layers, 1,
+    T, ...) with T >= the pages' token span.  The first
     ``len(page_ids) * page_size`` positions are copied page-by-page;
     garbage past ``n_tokens`` lands in the owned pages' tails, where the
     length mask hides it.
@@ -230,11 +234,10 @@ def insert_pages(paged_cache, one_cache, page_ids, n_tokens: int):
     ids = jnp.asarray(page_ids, jnp.int32)
 
     def one(pages, dense):
-        ps = pages.shape[2]
+        L, ps, W = pages.shape[0], pages.shape[2], pages.shape[3]
         span = len(page_ids) * ps
-        chunks = dense[:, 0, :span].reshape(
-            dense.shape[0], len(page_ids), ps, *dense.shape[3:])
-        return pages.at[:, ids].set(chunks.astype(pages.dtype))
+        rows = to_pool_rows(dense[:, 0, :span].astype(pages.dtype), 2, W)
+        return pages.at[:, ids].set(rows.reshape(L, len(page_ids), ps, W))
 
     return jax.tree.map(one, paged_cache, one_cache)
 

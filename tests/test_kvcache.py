@@ -7,6 +7,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.layers.attention import (
+    paged_cache_insert, paged_gather, pool_row_width,
+)
 from repro.serving.kvcache import (
     PagePool, PagesExhausted, SlotPool, insert_pages,
 )
@@ -149,19 +152,65 @@ def test_block_table_is_a_copy():
 
 # ---- cache scatter helpers -----------------------------------------------
 
-def test_insert_pages_scatters_dense_prefill_into_pool():
-    layers, n_pages, ps, heads, dim = 2, 6, 4, 2, 3
-    paged = {"k": jnp.zeros((layers, n_pages, ps, heads, dim))}
-    T = 8
-    dense = {"k": jnp.arange(layers * T * heads * dim, dtype=jnp.float32)
-                  .reshape(layers, 1, T, heads, dim)}
+# paged leaves as ``paged_cache_specs`` lays them out: a token's row
+# (attention's K x D, MLA's latent R or rotary r) flattened onto one
+# minor axis of whole 128-lane tiles
+LEAF_ROWS = {"kv_padded": (2, 3), "kv_one_tile": (2, 64), "ckv": (512,),
+             "kr_padded": (64,)}
+
+
+def _dense_and_pool(row, layers=2, n_pages=6, ps=4, T=8):
+    n = int(np.prod(row))
+    dense = jnp.arange(layers * T * n, dtype=jnp.float32).reshape(
+        layers, 1, T, *row) + 1.0
+    paged = jnp.zeros((layers, n_pages, ps, pool_row_width(n)))
+    return dense, paged
+
+
+@pytest.mark.parametrize("leaf", sorted(LEAF_ROWS))
+def test_insert_pages_scatters_dense_prefill_into_pool(leaf):
+    row = LEAF_ROWS[leaf]
+    n = int(np.prod(row))
+    dense, paged = _dense_and_pool(row)
+    ps = paged.shape[2]
+    assert paged.shape[3] % 128 == 0 and paged.shape[3] - n < 128
     pages = [4, 1]                       # deliberately non-contiguous
-    out = insert_pages(paged, dense, pages, n_tokens=T)
+    out = insert_pages({"k": paged}, {"k": dense}, pages, n_tokens=8)
     got = np.asarray(out["k"])
-    want = np.asarray(dense["k"][:, 0])
+    want = np.asarray(dense[:, 0]).reshape(dense.shape[0], -1, n)
     for j, pid in enumerate(pages):
-        np.testing.assert_array_equal(got[:, pid],
+        np.testing.assert_array_equal(got[:, pid, :, :n],
                                       want[:, j * ps:(j + 1) * ps])
+    assert (got[..., n:] == 0).all()     # padding lanes stay zero
     # untouched pages stay zero
-    for pid in set(range(n_pages)) - set(pages):
+    for pid in set(range(paged.shape[1])) - set(pages):
+        assert (got[:, pid] == 0).all()
+
+
+@pytest.mark.parametrize("leaf", sorted(LEAF_ROWS))
+def test_prefill_rows_land_where_the_decode_gather_reads(leaf):
+    """A prefill's tokens, inserted page by page, come back in order
+    through the decode step's gather of each layer; a position the
+    decode step then writes lands behind them."""
+    row = LEAF_ROWS[leaf]
+    dense, paged = _dense_and_pool(row, T=12)   # spans the owned pages
+    T = 8
+    pages = [5, 2, 3]                    # 8 tokens, then room to decode
+    pool = insert_pages({"k": paged}, {"k": dense}, pages, n_tokens=T)["k"]
+    tables = jnp.asarray([pages, [0, 0, 0]], jnp.int32)   # row 1 dead
+    lengths = jnp.asarray([T, 0], jnp.int32)
+    new = jnp.full((2, 1, *row), -7.0)
+    for layer in range(dense.shape[0]):
+        pool = paged_cache_insert(pool, layer, new, tables, lengths)
+        seq = np.asarray(paged_gather(pool, layer, tables, row))
+        assert seq.shape == (2, 3 * paged.shape[2], *row)
+        want = np.asarray(dense[layer, 0])
+        np.testing.assert_array_equal(seq[0, :T], want[:T])
+        np.testing.assert_array_equal(seq[0, T], np.asarray(new[0, 0]))
+        np.testing.assert_array_equal(seq[0, T + 1:], want[T + 1:])
+    # the dead row wrote only to the dummy page 0
+    got = np.asarray(pool)
+    n = int(np.prod(row))
+    assert (got[:, 0, 0, :n] == -7).all() and (got[:, 0, 1:] == 0).all()
+    for pid in set(range(1, paged.shape[1])) - set(pages):
         assert (got[:, pid] == 0).all()
