@@ -74,7 +74,7 @@ class ArchConfig:
     slstm_proj_factor: float = 1.3334
     xlstm_chunk: int = 128
     # unrolling the sLSTM time scan lets XLA CSE the recurrent-weight reads
-    # across steps: HBM traffic of R drops by the unroll factor (§Perf)
+    # across steps: HBM traffic of R drops by the unroll factor
     slstm_unroll: int = 1
 
     # --- encoder-decoder (whisper) ---
@@ -145,7 +145,6 @@ class TrainConfig:
     compute_dtype: str = "bfloat16"
     remat: str = "full"              # none | full | dots
     microbatches: int = 1
-    z_loss: float = 0.0
     grad_compression: str = "none"   # none | int8
     seed: int = 0
 

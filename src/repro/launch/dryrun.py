@@ -30,8 +30,8 @@ OUT_DIR = REPO / "results" / "dryrun"
 
 
 # ---------------------------------------------------------------------------
-# perf-hillclimb variants (§Perf in EXPERIMENTS.md): each is a named bundle
-# of rule overrides / train-config / build options / arch-config tweaks.
+# --perf-variant: each is a named bundle of rule overrides / train-config /
+# build options (remat) / arch-config tweaks applied to one cell.
 # ---------------------------------------------------------------------------
 VARIANTS: dict[str, dict] = {
     "baseline": {},
@@ -51,37 +51,10 @@ VARIANTS: dict[str, dict] = {
     # the model axis and heads win -> scores replicate.  sp2 releases the
     # (undivisible) head sharding so the sequence keeps the axis.
     "sp2": {"rules": {"seq": "model", "heads": None, "kv_heads": None}},
-    # sp3: SP + explicit kv replication so the scores keep the seq shard
-    "sp3": {"rules": {"seq": "model"}, "opts": {"attn_sp": True}},
-    # bf16 masked-softmax chain (serving-grade numerics): halves the
-    # dominant score-chain traffic the XLA path materializes
-    "bf16sm": {"opts": {"softmax_dtype": "bfloat16"}},
     # force partial-matmul+all-reduce at decode: shard the activations'
     # hidden dim over data so it MATCHES the weights' contraction-dim
     # sharding (GSPMD only picks partial+AR on matched shardings)
     "actshard": {"rules": {"batch": None, "act_embed": "data"}},
-    # one-hot masked KV-cache update: partitions elementwise over the
-    # seq-sharded cache instead of GSPMD's involuntary full remat of the
-    # scatter operand
-    "blend": {"opts": {"cache_update": "blend"}},
-    "blendshard": {"rules": {"batch": None, "act_embed": "data"},
-                   "opts": {"cache_update": "blend"}},
-    # shard_map cache insert: each chip updates its local (batch, seq)
-    # tile; no involuntary remat, zero collectives for the update
-    "cacheshard": {"opts": {"cache_update": "shard"}},
-    # gather q (tiny) instead of the cache: distributed partial-softmax
-    # decode attention over the seq-sharded cache
-    "gatherq": {"opts": {"decode_attn": "gatherq"}},
-    "gatherqshard": {"opts": {"decode_attn": "gatherq",
-                              "cache_update": "shard"}},
-    # full manual control: shard_map distributed-softmax decode attention
-    # + shard_map cache insert (flash-decoding communication pattern)
-    "smattn": {"opts": {"decode_attn": "shardmap",
-                        "cache_update": "shard"}},
-    # + activation hidden-dim sharding over data: weight FSDP gathers
-    # become partial-matmul + small all-reduces
-    "smattn2": {"opts": {"decode_attn": "shardmap", "cache_update": "shard"},
-                "rules": {"batch": None, "act_embed": "data"}},
     # sLSTM scan unroll: recurrent weights CSE across unrolled steps
     "slstm8": {"cfg": {"slstm_unroll": 8}},
     "slstm32": {"cfg": {"slstm_unroll": 32}},
@@ -312,7 +285,8 @@ def main():
     ap.add_argument("--arch")
     ap.add_argument("--shape")
     ap.add_argument("--multi-pod", action="store_true")
-    ap.add_argument("--perf-variant", default="baseline")
+    ap.add_argument("--perf-variant", default="baseline",
+                    choices=sorted(VARIANTS))
     ap.add_argument("--save-hlo", action="store_true")
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--jobs", type=int, default=4)

@@ -1,13 +1,12 @@
-"""GQA attention with sliding-window masks and logit softcapping.
-
-The XLA path (default) is what the dry-run lowers; a Pallas flash kernel
-(repro.kernels) can be selected with ``impl="pallas"`` for TPU execution
-or ``impl="pallas_interpret"`` for CPU validation.
+"""GQA attention with sliding-window masks and logit softcapping, in
+XLA, with its dense and paged KV-cache updates.
 
 API:
   project_qkv(params, x, positions, cfg)   -> q, k, v (rope applied)
   gqa_scores(q, k, v, ...)                 -> attention output (pre-wo)
   attention_apply(params, x, ...)          -> full self-attention (train/prefill)
+  cache_insert / paged_cache_insert        -> one decode token's K/V row written
+  paged_gather                             -> each row's pages, contiguous
 """
 
 from __future__ import annotations
@@ -62,7 +61,6 @@ def gqa_scores(
     softcap: float = 0.0,
     kv_valid: Optional[jax.Array] = None,   # (B, T) bool — cache validity
     scale: Optional[float] = None,
-    softmax_dtype=jnp.float32,
 ):
     """Grouped-query attention core.
 
@@ -80,7 +78,7 @@ def gqa_scores(
     if G > 1:
         k = jnp.repeat(k, G, axis=2)
         v = jnp.repeat(v, G, axis=2)
-    logits = jnp.einsum("bshd,bthd->bhst", q, k).astype(softmax_dtype) * scale
+    logits = jnp.einsum("bshd,bthd->bhst", q, k).astype(jnp.float32) * scale
     logits = _softcap(logits, softcap)
 
     qp = q_positions[:, :, None]                      # (B, S, 1)
@@ -92,10 +90,8 @@ def gqa_scores(
         mask &= kp > qp - window
     if kv_valid is not None:
         mask &= kv_valid[:, None, :]
-    neg = jnp.asarray(NEG_INF if softmax_dtype == jnp.float32 else -3e38,
-                      softmax_dtype) if softmax_dtype == jnp.float32 else \
-        jnp.asarray(jnp.finfo(softmax_dtype).min, softmax_dtype)
-    logits = jnp.where(mask[:, None, :, :], logits, neg)
+    logits = jnp.where(mask[:, None, :, :], logits,
+                       jnp.asarray(NEG_INF, jnp.float32))
 
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     return jnp.einsum("bhst,bthd->bshd", probs, v)
@@ -109,10 +105,6 @@ def attention_apply(
     causal: bool = True,
     cross_kv=None,            # (k, v) from an encoder for cross-attention
     cross_positions=None,
-    impl: str = "xla",
-    constrain_kv=None,        # SP: pin k/v replicated over model so the
-                              # scores keep the seq sharding (see §Perf)
-    softmax_dtype=jnp.float32,
 ):
     """Self- (or cross-) attention over the given sequence (train / prefill).
 
@@ -130,26 +122,12 @@ def attention_apply(
         return output_proj(params, out, x.dtype), (k, v)
 
     q, k, v = project_qkv(params, x, positions, cfg)
-    if constrain_kv is not None:
-        k = constrain_kv(k)
-        v = constrain_kv(v)
-    window = cfg.sliding_window if local else 0
-
-    if impl in ("pallas", "pallas_interpret"):
-        from repro.kernels import ops as kops
-
-        out = kops.flash_attention(
-            q, k, v,
-            causal=causal, window=window, softcap=cfg.attn_logit_softcap,
-            interpret=(impl == "pallas_interpret"),
-        )
-    else:
-        out = gqa_scores(
-            q, k, v,
-            q_positions=positions, kv_positions=positions,
-            causal=causal, window=window, softcap=cfg.attn_logit_softcap,
-            softmax_dtype=softmax_dtype,
-        )
+    out = gqa_scores(
+        q, k, v,
+        q_positions=positions, kv_positions=positions, causal=causal,
+        window=cfg.sliding_window if local else 0,
+        softcap=cfg.attn_logit_softcap,
+    )
     return output_proj(params, out, x.dtype), (k, v)
 
 
@@ -160,110 +138,10 @@ def cross_kv_project(params, enc_out, cfg):
     return k, v
 
 
-def decode_attention_shardmap(q, k_cache, v_cache, lengths, *, mesh, rules,
-                              window: int = 0, softcap: float = 0.0):
-    """Distributed partial-softmax decode attention under shard_map.
-
-    q: (B, 1, H, D) batch-sharded; cache: (B, T, K, D) batch-sharded over
-    the data axes and seq-sharded over 'model'.  Each chip computes
-    logits/softmax partials over its local seq tile; a pmax + two psums
-    (scalars and (B,H,D)) combine — the cache never moves.  This is the
-    flash-decoding communication pattern expressed manually because
-    GSPMD keeps resolving the q-heads/cache-seq sharding conflict by
-    all-gathering the cache (measured: 270 GB/step on llama3-405b).
-    """
-    import math as _math
-
-    from repro.common.sharding import spec_for
-
-    B, _, H, D = q.shape
-    T, K = k_cache.shape[1], k_cache.shape[2]
-    G = H // K
-    scale = 1.0 / _math.sqrt(D)
-    # q follows the CACHE's batch sharding (pjit auto-reshards q if the
-    # activation rules keep it replicated)
-    spec_q = spec_for(q.shape, ("cache_batch", None, None, None), rules, mesh)
-    spec_c = spec_for(k_cache.shape,
-                      ("cache_batch", "cache_seq", None, None), rules, mesh)
-    spec_l = spec_for(lengths.shape, ("cache_batch",), rules, mesh)
-    t_entry = spec_c[1]
-    seq_axes = (() if t_entry is None else
-                (t_entry if isinstance(t_entry, tuple) else (t_entry,)))
-
-    def f(q_l, k_l, v_l, len_l):
-        B_loc, _, _, _ = q_l.shape
-        T_loc = k_l.shape[1]
-        t_off = jnp.zeros((), jnp.int32)
-        idx = 0
-        for a in seq_axes:
-            idx = idx * mesh.shape[a] + jax.lax.axis_index(a)
-        t_off = (idx * T_loc) if seq_axes else 0
-        kv_pos = t_off + jnp.arange(T_loc, dtype=jnp.int32)       # (T_loc,)
-        if G > 1:
-            k_rep = jnp.repeat(k_l, G, axis=2)
-            v_rep = jnp.repeat(v_l, G, axis=2)
-        else:
-            k_rep, v_rep = k_l, v_l
-        logits = jnp.einsum("bshd,bthd->bhst", q_l,
-                            k_rep.astype(q_l.dtype)).astype(jnp.float32) * scale
-        if softcap and softcap > 0.0:
-            logits = softcap * jnp.tanh(logits / softcap)
-        pos = len_l[:, None]                                       # (B,1)
-        valid = kv_pos[None, :] < (len_l + 1)[:, None]             # (B,T_loc)
-        if window and window > 0:
-            valid &= kv_pos[None, :] > pos - window
-        logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
-        m_loc = jnp.max(logits, axis=-1)                           # (B,H,1)
-        if seq_axes:
-            m = jax.lax.pmax(m_loc, seq_axes if len(seq_axes) > 1
-                             else seq_axes[0])
-        else:
-            m = m_loc
-        safe_m = jnp.where(m > NEG_INF / 2, m, 0.0)
-        p = jnp.exp(logits - safe_m[..., None])
-        p = jnp.where(valid[:, None, None, :], p, 0.0)
-        s_loc = jnp.sum(p, axis=-1)                                # (B,H,1)
-        o_loc = jnp.einsum("bhst,bthd->bshd", p.astype(q_l.dtype), v_rep)
-        if seq_axes:
-            ax = seq_axes if len(seq_axes) > 1 else seq_axes[0]
-            s = jax.lax.psum(s_loc, ax)
-            o = jax.lax.psum(o_loc.astype(jnp.float32), ax)
-        else:
-            s, o = s_loc, o_loc.astype(jnp.float32)
-        out = o / jnp.maximum(s, 1e-30).transpose(0, 2, 1)[..., None]
-        return out.astype(q_l.dtype)
-
-    return jax.shard_map(
-        f, mesh=mesh,
-        in_specs=(spec_q, spec_c, spec_c, spec_l),
-        out_specs=spec_q, check_vma=False,
-    )(q, k_cache, v_cache, lengths)
-
-
-def cache_insert(cache_arr, new_val, lengths, *, mode: str = "scatter",
-                 mesh=None, rules=None):
+def cache_insert(cache_arr, new_val, lengths):
     """Insert new_val (B, 1, ...) into cache (B, T, ...) at per-batch
-    position `lengths`.
-
-    mode="scatter": gather/scatter update — natural but hostile to a
-    seq-sharded cache (GSPMD replicates the operand: "involuntary full
-    rematerialization", measured as a full-cache all-gather per layer).
-    mode="blend": one-hot masked rewrite — elementwise, but the traffic
-    model charges a full cache rewrite (measured worse; kept as a
-    refuted-hypothesis record, see EXPERIMENTS.md §Perf).
-    mode="shard": shard_map update — each chip scatters into its local
-    (batch, seq) tile only when the position falls inside it; exactly
-    partitioned, zero collectives.
-    """
-    B, T = cache_arr.shape[:2]
-    if mode == "shard" and mesh is not None:
-        return _cache_insert_shardmap(cache_arr, new_val, lengths, mesh, rules)
-    if mode == "blend":
-        onehot = (jnp.arange(T, dtype=jnp.int32)[None, :]
-                  == lengths[:, None])                       # (B, T)
-        oh = onehot.reshape(B, T, *([1] * (cache_arr.ndim - 2)))
-        newb = new_val[:, :1].astype(cache_arr.dtype)        # (B,1,...)
-        return jnp.where(oh, newb, cache_arr)
+    position `lengths`."""
+    B = cache_arr.shape[0]
     return cache_arr.at[jnp.arange(B), lengths].set(
         new_val[:, 0].astype(cache_arr.dtype))
 
@@ -323,40 +201,3 @@ def paged_gather(pool, layer, block_tables, row_shape):
     B, n_max = block_tables.shape
     seq = pool[layer, block_tables].reshape(B, n_max * pool.shape[2], -1)
     return from_pool_rows(seq, row_shape)
-
-
-def _cache_insert_shardmap(cache_arr, new_val, lengths, mesh, rules):
-    import numpy as np
-
-    from repro.common.sharding import spec_for
-
-    nd = cache_arr.ndim
-    axes_c = ("cache_batch", "cache_seq") + (None,) * (nd - 2)
-    spec_c = spec_for(cache_arr.shape, axes_c, rules, mesh)
-    axes_n = ("cache_batch", None) + (None,) * (nd - 2)
-    spec_n = spec_for(new_val.shape, axes_n, rules, mesh)
-    spec_l = spec_for(lengths.shape, ("cache_batch",), rules, mesh)
-    t_entry = spec_c[1]
-
-    def f(c, nv, ln):
-        B_loc, T_loc = c.shape[:2]
-        t_off = 0
-        if t_entry is not None:
-            names = t_entry if isinstance(t_entry, tuple) else (t_entry,)
-            idx = 0
-            for a in names:
-                idx = idx * mesh.shape[a] + jax.lax.axis_index(a)
-            t_off = idx * T_loc
-        pos = ln - t_off                                     # (B_loc,)
-        inb = (pos >= 0) & (pos < T_loc)
-        posc = jnp.clip(pos, 0, T_loc - 1)
-        rows = jnp.arange(B_loc)
-        old = c[rows, posc]
-        mask = inb.reshape(-1, *([1] * (nd - 2)))
-        new_rows = jnp.where(mask, nv[:, 0].astype(c.dtype), old)
-        return c.at[rows, posc].set(new_rows)
-
-    return jax.shard_map(
-        f, mesh=mesh, in_specs=(spec_c, spec_n, spec_l), out_specs=spec_c,
-        check_vma=False,
-    )(cache_arr, new_val, lengths)

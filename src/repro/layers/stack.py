@@ -11,8 +11,6 @@ state (KV caches in decode, collected caches in prefill).
 
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 
@@ -25,7 +23,7 @@ def remat_policy(name: str):
     return jax.checkpoint_policies.nothing_saveable  # "full"
 
 
-def scan_stack(fn, stacked_params, h, xs=None, *, remat: str = "full", unroll=1):
+def scan_stack(fn, stacked_params, h, xs=None, *, remat: str = "full"):
     """Scan `fn` over the leading (layer) axis of `stacked_params`.
 
     fn(layer_params, h, x_l) -> (h_new, y_l);  y_l may be None.
@@ -38,19 +36,16 @@ def scan_stack(fn, stacked_params, h, xs=None, *, remat: str = "full", unroll=1)
         return h_new, y_l
 
     n = jax.tree.leaves(stacked_params)[0].shape[0]
-    if unroll is True:
-        unroll = n
-    unroll = max(1, min(int(unroll), n))
     if remat != "none":
-        # prevent_cse=False is safe (and faster) only under an actual scan
-        # loop; with unrolled bodies CSE would silently defeat remat.
+        # prevent_cse=False is safe (and faster) under a scan loop: the
+        # body is not unrolled, so CSE cannot defeat remat
         body = jax.checkpoint(body, policy=remat_policy(remat),
-                              prevent_cse=(unroll > 1))
+                              prevent_cse=False)
     if xs is None:
         xs_t = (stacked_params, _nones(n))
     else:
         xs_t = (stacked_params, xs)
-    h_final, ys = jax.lax.scan(body, h, xs_t, unroll=unroll)
+    h_final, ys = jax.lax.scan(body, h, xs_t)
     return h_final, ys
 
 

@@ -119,22 +119,14 @@ def _constrainer(mesh, rules):
     return constrain
 
 
-def _make_ctx(cfg, mesh, rules, mode, positions, lengths, opts):
+def _make_ctx(mesh, rules, mode, positions, lengths, remat):
     return {
         "mode": mode,
         "positions": positions,
         "lengths": lengths,
         "mesh": mesh,
-        "remat": opts.get("remat", "full") if mode == "train" else "none",
-        "moe_impl": opts.get("moe_impl", "ep" if mesh is not None else "dense"),
-        "attn_impl": opts.get("attn_impl", "xla"),
-        "unroll": opts.get("scan_unroll", False),
-        "cache_update": opts.get("cache_update", "scatter"),
-        "decode_attn": opts.get("decode_attn", "default"),
-        "paged_attn": opts.get("paged_attn", "xla"),
-        "attn_sp": opts.get("attn_sp", False),
-        "softmax_dtype": opts.get("softmax_dtype", jnp.float32),
-        "rules": rules,
+        "remat": remat if mode == "train" else "none",
+        "moe_impl": "ep" if mesh is not None else "dense",
         "constrain": _constrainer(mesh, rules),
     }
 
@@ -159,10 +151,8 @@ def _run_backbone(stages, params, h, ctx, caches):
             y = cache_l if has_cache else jnp.zeros((0,))
             return (c2[0], c2[1]), y
 
-        carry, ys = scan_stack(
-            fn, p_st["blocks"], carry, xs=cache_st, remat=ctx["remat"],
-            unroll=ctx.get("unroll", False),
-        )
+        carry, ys = scan_stack(fn, p_st["blocks"], carry, xs=cache_st,
+                               remat=ctx["remat"])
         if st.routed and ctx.get("token_valid") is not None:
             ys, c = ys
             counts.append(c)
@@ -197,8 +187,7 @@ def _run_paged_decode(stages, params, h, ctx, pools):
 
         (h, aux, new_pools[st.name]), c = jax.lax.scan(
             body, (h, aux, pools[st.name]),
-            (params["stages"][st.name]["blocks"], jnp.arange(st.n)),
-            unroll=ctx.get("unroll", False))
+            (params["stages"][st.name]["blocks"], jnp.arange(st.n)))
         if st.routed and count:
             counts.append(c)
     return (h, new_pools,
@@ -261,16 +250,13 @@ def _logits(cfg, params, h):
                       tied_table=tied)
 
 
-def cross_entropy(logits, targets, mask, z_loss=0.0):
+def cross_entropy(logits, targets, mask):
     logits = logits.astype(F32)
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
     tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
     ce = (lse - tgt) * mask
     denom = jnp.maximum(mask.sum(), 1.0)
-    loss = ce.sum() / denom
-    if z_loss:
-        loss = loss + z_loss * ((lse * mask) ** 2).sum() / denom
-    return loss
+    return ce.sum() / denom
 
 
 def _mtp_loss(cfg, params, h, batch, ctx, compute_dtype):
@@ -302,34 +288,38 @@ def _mtp_loss(cfg, params, h, batch, ctx, compute_dtype):
     return cross_entropy(logits, tgt, msk)
 
 
-def build_model(cfg: ArchConfig, mesh=None, rules=None, **opts) -> ModelBundle:
+def build_model(cfg: ArchConfig, mesh=None, rules=None, *,
+                compute_dtype=jnp.bfloat16, remat: str = "full") -> ModelBundle:
+    """The bundle for ``cfg``.  Each step's attention, cache update and
+    expert dispatch follow from its mode and the mesh; ``remat`` is the
+    training step's rematerialization policy ("full", "dots", "none")."""
     if cfg.is_encoder_decoder:
-        return encdec_lib.build_encdec(cfg, mesh=mesh, rules=rules, **opts)
+        return encdec_lib.build_encdec(cfg, mesh=mesh, rules=rules,
+                                       compute_dtype=compute_dtype,
+                                       remat=remat)
 
     rules = merge_rules(rules if isinstance(rules, dict) else None)
     stages = make_stages(cfg)
     specs = _lm_specs(cfg, stages)
-    compute_dtype = opts.get("compute_dtype", jnp.bfloat16)
 
     # ---- loss (train) ----
     def loss_fn(params, batch):
         h, n_prefix = _embed_inputs(cfg, params, batch, compute_dtype)
         B, S = h.shape[:2]
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-        ctx = _make_ctx(cfg, mesh, rules, "train", positions, None, opts)
+        ctx = _make_ctx(mesh, rules, "train", positions, None, remat)
         h = ctx["constrain"](h)
         h, aux, _, _ = _run_backbone(stages, params, h, ctx, None)
         h = apply_norm(params["final_norm"], h, cfg.norm, cfg.norm_eps)
         if n_prefix:
             h = h[:, n_prefix:]
         logits = _logits(cfg, params, h)
-        loss = cross_entropy(logits, batch["targets"], batch["mask"],
-                             opts.get("z_loss", 0.0))
+        loss = cross_entropy(logits, batch["targets"], batch["mask"])
         metrics = {"ce": loss, "aux": aux}
         if cfg.router_aux_loss and cfg.n_experts:
             loss = loss + cfg.router_aux_loss * aux
         if cfg.mtp_depth:
-            ctx_m = _make_ctx(cfg, mesh, rules, "train", positions, None, opts)
+            ctx_m = _make_ctx(mesh, rules, "train", positions, None, remat)
             mtp = _mtp_loss(cfg, params, h if not n_prefix else h,
                             batch, ctx_m, compute_dtype)
             metrics["mtp"] = mtp
@@ -349,7 +339,7 @@ def build_model(cfg: ArchConfig, mesh=None, rules=None, **opts) -> ModelBundle:
         lengths = batch.get("lengths")
         if lengths is None:
             lengths = jnp.full((B,), S, jnp.int32)
-        ctx = _make_ctx(cfg, mesh, rules, "prefill", positions, lengths, opts)
+        ctx = _make_ctx(mesh, rules, "prefill", positions, lengths, remat)
         if count_experts:
             ctx["token_valid"] = positions < lengths[:, None]
         h = ctx["constrain"](h)
@@ -368,7 +358,7 @@ def build_model(cfg: ArchConfig, mesh=None, rules=None, **opts) -> ModelBundle:
             dtype=compute_dtype)
         B = h.shape[0]
         positions = lengths[:, None].astype(jnp.int32)
-        ctx = _make_ctx(cfg, mesh, rules, "decode", positions, lengths, opts)
+        ctx = _make_ctx(mesh, rules, "decode", positions, lengths, remat)
         h, _, new_caches, _ = _run_backbone(stages, params, h, ctx, cache)
         h = apply_norm(params["final_norm"], h, cfg.norm, cfg.norm_eps)
         logits = _logits(cfg, params, h)[:, 0]
@@ -389,7 +379,7 @@ def build_model(cfg: ArchConfig, mesh=None, rules=None, **opts) -> ModelBundle:
             scale=math.sqrt(cfg.d_model) if cfg.embed_scale_by_dim else 1.0,
             dtype=compute_dtype)
         positions = lengths[:, None].astype(jnp.int32)
-        ctx = _make_ctx(cfg, mesh, rules, "decode", positions, lengths, opts)
+        ctx = _make_ctx(mesh, rules, "decode", positions, lengths, remat)
         ctx["cache_layout"] = "paged"
         ctx["block_tables"] = block_tables
         if count_experts:

@@ -69,7 +69,6 @@ def _enc_block(p, h, ctx, cfg):
     x = apply_norm(p["ln_attn"], h, cfg.norm, cfg.norm_eps)
     y, _ = attn_lib.attention_apply(
         p["attn"], x, positions=ctx["positions"], cfg=cfg, causal=False,
-        impl=ctx.get("attn_impl", "xla"),
     )
     h = h + y
     x = apply_norm(p["ln_mlp"], h, cfg.norm, cfg.norm_eps)
@@ -98,13 +97,8 @@ def _dec_block(p, h, cache, ctx, cfg, enc_out, enc_positions):
     else:
         lengths = ctx["lengths"]
         q, k_new, v_new = attn_lib.project_qkv(p["self_attn"], x, positions, cfg)
-        mode = ctx.get("cache_update", "scatter")
-        k_c = attn_lib.cache_insert(cache["self"]["k"], k_new, lengths,
-                                    mode=mode, mesh=ctx.get("mesh"),
-                                    rules=ctx.get("rules"))
-        v_c = attn_lib.cache_insert(cache["self"]["v"], v_new, lengths,
-                                    mode=mode, mesh=ctx.get("mesh"),
-                                    rules=ctx.get("rules"))
+        k_c = attn_lib.cache_insert(cache["self"]["k"], k_new, lengths)
+        v_c = attn_lib.cache_insert(cache["self"]["v"], v_new, lengths)
         T = k_c.shape[1]
         kv_pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
         kv_valid = kv_pos < (lengths + 1)[:, None]
@@ -142,28 +136,27 @@ def _dec_block(p, h, cache, ctx, cfg, enc_out, enc_positions):
     return h, new_cache
 
 
-def _encode(cfg, params, frames, compute_dtype, opts):
+def _encode(cfg, params, frames, compute_dtype, remat):
     B, S = frames.shape[:2]
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     h = frames.astype(compute_dtype)
     h = jnp.einsum("bsd,de->bse", h, params["audio_proj"]["w"].astype(compute_dtype))
     h = h + sinusoid(positions, cfg.d_model).astype(compute_dtype)
-    ctx = {"positions": positions, "attn_impl": opts.get("attn_impl", "xla")}
+    ctx = {"positions": positions}
 
     def fn(lp, c, x_l):
         return _enc_block(lp, c, ctx, cfg), jnp.zeros((0,))
 
-    h, _ = scan_stack(fn, params["encoder"], h, remat=opts.get("remat", "full"),
-                      unroll=opts.get("scan_unroll", False))
+    h, _ = scan_stack(fn, params["encoder"], h, remat=remat)
     h = apply_norm(params["enc_norm"], h, cfg.norm, cfg.norm_eps)
     return h, positions
 
 
-def build_encdec(cfg, mesh=None, rules=None, **opts):
+def build_encdec(cfg, mesh=None, rules=None, *, compute_dtype=jnp.bfloat16,
+                 remat: str = "full"):
     from repro.models.api import ModelBundle, _constrainer, cross_entropy
 
     rules = merge_rules(rules if isinstance(rules, dict) else None)
-    compute_dtype = opts.get("compute_dtype", jnp.bfloat16)
     n_dec = cfg.n_layers
 
     specs: dict[str, Any] = {
@@ -191,19 +184,16 @@ def build_encdec(cfg, mesh=None, rules=None, **opts):
             return (hh, c[1]), (cc if has_cache else jnp.zeros((0,)))
 
         carry, ys = scan_stack(fn, params["decoder"], (h, jnp.zeros((), F32)),
-                               xs=cache, remat=ctx["remat"],
-                               unroll=ctx.get("unroll", False))
+                               xs=cache, remat=ctx["remat"])
         return carry[0], (ys if cache is not None else None)
 
     def loss_fn(params, batch):
         enc_out, enc_pos = _encode(cfg, params, batch["audio_frames"],
-                                   compute_dtype, opts)
+                                   compute_dtype, remat)
         B, S = batch["tokens"].shape
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         ctx = {"mode": "train", "positions": positions, "lengths": None,
-               "remat": opts.get("remat", "full"),
-               "unroll": opts.get("scan_unroll", False),
-               "constrain": _constrainer(mesh, rules)}
+               "remat": remat, "constrain": _constrainer(mesh, rules)}
         h = _dec_embed(params, batch["tokens"], positions)
         h, _ = _run_decoder(params, h, ctx, None, enc_out, enc_pos)
         h = apply_norm(params["final_norm"], h, cfg.norm, cfg.norm_eps)
@@ -213,15 +203,14 @@ def build_encdec(cfg, mesh=None, rules=None, **opts):
 
     def prefill(params, batch, cache):
         enc_out, enc_pos = _encode(cfg, params, batch["audio_frames"],
-                                   compute_dtype, opts)
+                                   compute_dtype, remat)
         B, S = batch["tokens"].shape
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         lengths = batch.get("lengths")
         if lengths is None:
             lengths = jnp.full((B,), S, jnp.int32)
         ctx = {"mode": "prefill", "positions": positions, "lengths": lengths,
-               "remat": "none", "unroll": opts.get("scan_unroll", False),
-               "constrain": _constrainer(mesh, rules)}
+               "remat": "none", "constrain": _constrainer(mesh, rules)}
         h = _dec_embed(params, batch["tokens"], positions)
         h, new_cache = _run_decoder(params, h, ctx, cache, enc_out, enc_pos)
         h = apply_norm(params["final_norm"], h, cfg.norm, cfg.norm_eps)
@@ -234,8 +223,7 @@ def build_encdec(cfg, mesh=None, rules=None, **opts):
         B = tokens.shape[0]
         positions = lengths[:, None].astype(jnp.int32)
         ctx = {"mode": "decode", "positions": positions, "lengths": lengths,
-               "remat": "none", "unroll": opts.get("scan_unroll", False),
-               "constrain": _constrainer(mesh, rules)}
+               "remat": "none", "constrain": _constrainer(mesh, rules)}
         h = _dec_embed(params, tokens, positions)
         enc_pos = jnp.broadcast_to(
             jnp.arange(cfg.encoder_seq, dtype=jnp.int32), (B, cfg.encoder_seq))

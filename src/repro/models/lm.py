@@ -71,7 +71,7 @@ def _mamba_block_specs(cfg):
     return {"ln": norm_specs(cfg.d_model, cfg.norm), "mamba": m2.mamba2_specs(cfg)}
 
 
-def _shared_attn_specs(cfg):
+def _shared_attention_specs(cfg):
     d = cfg.d_model
     return {
         "ln_attn": norm_specs(d, cfg.norm),
@@ -85,40 +85,18 @@ def _shared_attn_specs(cfg):
 # block bodies
 # ---------------------------------------------------------------------------
 
-def _constrain_kv_fn(ctx):
-    """SP helper: replicate k/v over the model axis (an explicit small
-    gather) so q keeps the seq sharding through the scores einsum —
-    without this GSPMD resolves the double-use of the model axis by
-    replicating the quadratic scores (§Perf)."""
-    if not ctx.get("attn_sp") or ctx.get("mesh") is None:
-        return None
-    from repro.common.sharding import spec_for
-
-    def constrain(kv):
-        spec = spec_for(kv.shape, ("batch", None, None, None),
-                        ctx["rules"], ctx["mesh"])
-        return jax.lax.with_sharding_constraint(
-            kv, jax.sharding.NamedSharding(ctx["mesh"], spec))
-
-    return constrain
-
-
 def _apply_attn_sub(p, h, cache, ctx, cfg, *, local: bool, post_norm: bool):
     """Norm + attention + residual (+post-norm). Returns (h, new_cache)."""
     x = apply_norm(p["ln_attn"], h, cfg.norm, cfg.norm_eps)
-    ckv = _constrain_kv_fn(ctx)
-    smd = ctx.get("softmax_dtype", jnp.float32)
     if ctx["mode"] == "train":
         y, _ = attn.attention_apply(
             p["attn"], x, positions=ctx["positions"], cfg=cfg, local=local,
-            impl=ctx["attn_impl"], constrain_kv=ckv, softmax_dtype=smd,
         )
         new_cache = cache
     elif ctx["mode"] == "prefill":
         S = x.shape[1]
         y, (k, v) = attn.attention_apply(
             p["attn"], x, positions=ctx["positions"], cfg=cfg, local=local,
-            constrain_kv=ckv, softmax_dtype=smd,
         )
         new_cache = {
             "k": cache["k"].at[:, :S].set(k.astype(cache["k"].dtype)),
@@ -129,42 +107,21 @@ def _apply_attn_sub(p, h, cache, ctx, cfg, *, local: bool, post_norm: bool):
         q_pos = ctx["positions"]
         lengths = ctx["lengths"]
         q, k_new, v_new = attn.project_qkv(p["attn"], x, q_pos, cfg)
-        if ctx.get("decode_attn") == "gatherq" and ctx["mesh"] is not None:
-            # Release q's head sharding (a ~MB gather) so the seq-sharded
-            # cache is consumed by distributed partial-softmax attention
-            # instead of being all-gathered every layer (§Perf).
-            from repro.common.sharding import spec_for
-
-            spec = spec_for(q.shape, ("batch", None, None, None),
-                            ctx["rules"], ctx["mesh"])
-            q = jax.lax.with_sharding_constraint(
-                q, jax.sharding.NamedSharding(ctx["mesh"], spec))
         if ctx.get("cache_layout") == "paged":
-            return _paged_attn_decode(p, h, x, cache, q, k_new, v_new,
-                                      ctx, cfg, local=local,
-                                      post_norm=post_norm)
-        mode = ctx.get("cache_update", "scatter")
-        k_cache = attn.cache_insert(cache["k"], k_new, lengths, mode=mode,
-                                    mesh=ctx["mesh"], rules=ctx.get("rules"))
-        v_cache = attn.cache_insert(cache["v"], v_new, lengths, mode=mode,
-                                    mesh=ctx["mesh"], rules=ctx.get("rules"))
-        if ctx.get("decode_attn") == "shardmap" and ctx["mesh"] is not None:
-            out = attn.decode_attention_shardmap(
-                q, k_cache, v_cache, lengths,
-                mesh=ctx["mesh"], rules=ctx["rules"],
-                window=(cfg.sliding_window if local else 0),
-                softcap=cfg.attn_logit_softcap,
-            )
-        else:
-            T = k_cache.shape[1]
-            kv_pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-            kv_valid = kv_pos < (lengths + 1)[:, None]
-            out = attn.gqa_scores(
-                q, k_cache.astype(x.dtype), v_cache.astype(x.dtype),
-                q_positions=q_pos, kv_positions=kv_pos,
-                causal=True, window=(cfg.sliding_window if local else 0),
-                softcap=cfg.attn_logit_softcap, kv_valid=kv_valid,
-            )
+            return _paged_decode_sub(p, h, x, cache, q, k_new, v_new,
+                                     ctx, cfg, local=local,
+                                     post_norm=post_norm)
+        k_cache = attn.cache_insert(cache["k"], k_new, lengths)
+        v_cache = attn.cache_insert(cache["v"], v_new, lengths)
+        T = k_cache.shape[1]
+        kv_pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+        kv_valid = kv_pos < (lengths + 1)[:, None]
+        out = attn.gqa_scores(
+            q, k_cache.astype(x.dtype), v_cache.astype(x.dtype),
+            q_positions=q_pos, kv_positions=kv_pos,
+            causal=True, window=(cfg.sliding_window if local else 0),
+            softcap=cfg.attn_logit_softcap, kv_valid=kv_valid,
+        )
         y = attn.output_proj(p["attn"], out, x.dtype)
         new_cache = {"k": k_cache, "v": v_cache}
     if post_norm:
@@ -172,17 +129,14 @@ def _apply_attn_sub(p, h, cache, ctx, cfg, *, local: bool, post_norm: bool):
     return h + y, new_cache
 
 
-def _paged_attn_decode(p, h, x, cache, q, k_new, v_new, ctx, cfg, *,
-                       local: bool, post_norm: bool):
+def _paged_decode_sub(p, h, x, cache, q, k_new, v_new, ctx, cfg, *,
+                      local: bool, post_norm: bool):
     """Decode step against a paged KV cache: cache leaves are the
     stage's stacked page pools (layers, n_pages, page_size, W), a
     token's K*D row padded to W lanes, and this block is layer
     ``ctx["layer"]``; ``ctx["block_tables"]`` (B, n_max) names each
-    row's pages.  ``ctx["paged_attn"]`` picks the attention path:
-    "pallas"/"pallas_interpret" run the batched paged kernel on this
-    layer's (n_pages, page_size, K, D) view; "xla" (default, and any
-    local/windowed layer — the kernel has no window support) gathers
-    the owned pages and reuses gqa_scores."""
+    row's pages.  The owned pages are gathered and attended with
+    gqa_scores."""
     lengths = ctx["lengths"]
     tables = ctx["block_tables"]
     layer = ctx["layer"]
@@ -191,32 +145,18 @@ def _paged_attn_decode(p, h, x, cache, q, k_new, v_new, ctx, cfg, *,
                                       lengths)
     v_cache = attn.paged_cache_insert(cache["v"], layer, v_new, tables,
                                       lengths)
-    impl = ctx.get("paged_attn", "xla")
-    window = cfg.sliding_window if local else 0
     row = k_new.shape[2:]
-    if impl in ("pallas", "pallas_interpret") and not window:
-        from repro.kernels import ops as kops
-
-        def view(pool):
-            return attn.from_pool_rows(pool[layer], row).astype(x.dtype)
-
-        out = kops.paged_decode_attention(
-            q[:, 0], view(k_cache), view(v_cache),
-            tables, lengths + 1,
-            softcap=cfg.attn_logit_softcap,
-            interpret=(impl == "pallas_interpret"))[:, None]
-    else:
-        k_seq = attn.paged_gather(k_cache, layer, tables, row)
-        v_seq = attn.paged_gather(v_cache, layer, tables, row)
-        T = k_seq.shape[1]
-        kv_pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-        kv_valid = kv_pos < (lengths + 1)[:, None]
-        out = attn.gqa_scores(
-            q, k_seq.astype(x.dtype), v_seq.astype(x.dtype),
-            q_positions=ctx["positions"], kv_positions=kv_pos,
-            causal=True, window=window,
-            softcap=cfg.attn_logit_softcap, kv_valid=kv_valid,
-        )
+    k_seq = attn.paged_gather(k_cache, layer, tables, row)
+    v_seq = attn.paged_gather(v_cache, layer, tables, row)
+    T = k_seq.shape[1]
+    kv_pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    kv_valid = kv_pos < (lengths + 1)[:, None]
+    out = attn.gqa_scores(
+        q, k_seq.astype(x.dtype), v_seq.astype(x.dtype),
+        q_positions=ctx["positions"], kv_positions=kv_pos,
+        causal=True, window=cfg.sliding_window if local else 0,
+        softcap=cfg.attn_logit_softcap, kv_valid=kv_valid,
+    )
     y = attn.output_proj(p["attn"], out, x.dtype)
     if post_norm:
         y = apply_norm(p["ln_attn_post"], y, cfg.norm, cfg.norm_eps)
@@ -302,11 +242,8 @@ def _mla_block(p, carry, cache, ctx, cfg, *, use_moe: bool):
         lengths = ctx["lengths"]
         ckv_new, kr_new = mla_lib.mla_project_kv(
             p["attn"], x, ctx["positions"], cfg)
-        mode = ctx.get("cache_update", "scatter")
-        ckv_c = attn.cache_insert(cache["ckv"], ckv_new, lengths, mode=mode,
-                                  mesh=ctx["mesh"], rules=ctx.get("rules"))
-        kr_c = attn.cache_insert(cache["kr"], kr_new, lengths, mode=mode,
-                                 mesh=ctx["mesh"], rules=ctx.get("rules"))
+        ckv_c = attn.cache_insert(cache["ckv"], ckv_new, lengths)
+        kr_c = attn.cache_insert(cache["kr"], kr_new, lengths)
         T = ckv_c.shape[1]
         kv_pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
         kv_valid = kv_pos < (lengths + 1)[:, None]
@@ -489,7 +426,7 @@ def make_stages(cfg) -> list[StageDef]:
         n_super = cfg.n_layers // k
         tail = cfg.n_layers - n_super * k
         super_specs = {"mamba": stack_specs(_mamba_block_specs(cfg), k)}
-        shared = _shared_attn_specs(cfg)
+        shared = _shared_attention_specs(cfg)
 
         def super_fn(p, carry, cache, ctx, k=k):
             mcache = None if cache is None else cache["mamba"]
@@ -500,8 +437,7 @@ def make_stages(cfg) -> list[StageDef]:
                 return cc, (cl if mcache is not None else jnp.zeros((0,)))
 
             carry, mc = scan_stack(inner, p["mamba"], carry, xs=mcache,
-                                   remat=ctx["remat"],
-                                   unroll=ctx.get("unroll", False))
+                                   remat=ctx["remat"])
             # shared attention block (weights shared across superblocks)
             acache = None if cache is None else cache["attn"]
             carry, ac = _attn_block(ctx["shared_attn"], carry, acache, ctx, cfg,
@@ -545,8 +481,7 @@ def make_stages(cfg) -> list[StageDef]:
                 return cc, (cl if mcache is not None else jnp.zeros((0,)))
 
             carry, mc = scan_stack(inner, p["mlstm"], carry, xs=mcache,
-                                   remat=ctx["remat"],
-                                   unroll=ctx.get("unroll", False))
+                                   remat=ctx["remat"])
             scache = None if cache is None else cache["slstm"]
             carry, sc = _slstm_block(p["slstm"], carry, scache, ctx, cfg)
             new_cache = None if cache is None else {"mlstm": mc, "slstm": sc}

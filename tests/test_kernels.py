@@ -12,7 +12,9 @@ try:                                  # property tests need hypothesis; the
 except ModuleNotFoundError:           # pragma: no cover - minimal install
     st = None
 
+from repro.common.config import get_config
 from repro.kernels import ops, ref
+from repro.layers import attention as attn
 
 TOLS = {jnp.float32: dict(rtol=2e-4, atol=2e-4),
         jnp.bfloat16: dict(rtol=3e-2, atol=3e-2)}
@@ -140,6 +142,51 @@ else:
     @pytest.mark.skip(reason="hypothesis not installed")
     def test_paged_decode_attention_random_lengths():
         pass
+
+
+def test_paged_decode_attention_reads_the_model_pool():
+    """The paged kernel reads one layer of the model's stacked page pool
+    (layers, n_pages, page_size, W), each token's K*D row zero-padded to
+    whole 128-lane tiles, through ``from_pool_rows``, and gives what the
+    served XLA path (``paged_gather`` + ``gqa_scores``) gives on live
+    rows, after this step's rows are written with ``paged_cache_insert``."""
+    cfg = get_config("internvl2-1b", smoke=True)
+    H, K, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    n_layers, n_pages, ps, layer = 2, 16, 4, 1
+    # rows 0, 1 and 3 live, row 2 dead; row 1 writes the first slot of
+    # a new page
+    tables = jnp.asarray([[3, 7, 1, 12], [5, 2, 9, 0], [0, 0, 0, 0],
+                          [4, 8, 11, 6]], jnp.int32)
+    lengths = jnp.asarray([3, 8, 0, 9], jnp.int32)
+    B, row = tables.shape[0], (K, D)
+    ks = jax.random.split(jax.random.PRNGKey(6), 5)
+    width = attn.pool_row_width(K * D)
+    assert width % 128 == 0 and width > K * D
+    # earlier tokens: random rows, zero padding lanes
+    pools = [jnp.zeros((n_layers, n_pages, ps, width)).at[..., :K * D].set(
+        jax.random.normal(kk, (n_layers, n_pages, ps, K * D)))
+        for kk in ks[:2]]
+    k_pool, v_pool = (
+        attn.paged_cache_insert(pool, layer,
+                                jax.random.normal(kk, (B, 1, K, D)),
+                                tables, lengths)
+        for pool, kk in zip(pools, ks[2:4]))
+    q = jax.random.normal(ks[4], (B, 1, H, D))
+    out = ops.paged_decode_attention(
+        q[:, 0], attn.from_pool_rows(k_pool[layer], row),
+        attn.from_pool_rows(v_pool[layer], row), tables, lengths + 1,
+        softcap=cfg.attn_logit_softcap, interpret=True)
+    k_seq = attn.paged_gather(k_pool, layer, tables, row)
+    v_seq = attn.paged_gather(v_pool, layer, tables, row)
+    T = k_seq.shape[1]
+    kv_pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    want = attn.gqa_scores(
+        q, k_seq, v_seq, q_positions=lengths[:, None], kv_positions=kv_pos,
+        causal=True, softcap=cfg.attn_logit_softcap,
+        kv_valid=kv_pos < (lengths + 1)[:, None])[:, 0]
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(np.asarray(out)[live], np.asarray(want)[live],
+                               rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("B,S,H,P,N,chunk", [

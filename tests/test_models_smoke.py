@@ -109,3 +109,16 @@ def test_moe_active_params_less_than_total():
     for arch in ("granite-moe-3b-a800m", "deepseek-v3-671b"):
         m = build_model(get_config(arch, smoke=True))
         assert m.active_param_count() < m.param_count()
+
+
+@pytest.mark.parametrize("arch", ["internvl2-1b", "whisper-tiny"])
+def test_build_model_takes_only_its_named_options(arch):
+    """The step's paths follow from its mode and the mesh: a removed
+    option is refused, not ignored, and the named ones still build."""
+    cfg = get_config(arch, smoke=True)
+    with pytest.raises(TypeError):
+        build_model(cfg, paged_attn="xla")
+    m = build_model(cfg, compute_dtype=jnp.float32, remat="dots")
+    params = jax.eval_shape(m.init, jax.random.PRNGKey(0))
+    loss, _ = jax.eval_shape(m.loss_fn, params, _batch(cfg))
+    assert loss.shape == () and loss.dtype == jnp.float32

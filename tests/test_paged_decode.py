@@ -179,32 +179,3 @@ def test_paged_decode_step_matches_per_layer_reference(arch):
             assert not np.asarray(a[..., int(np.prod(row)):]).any()
         tokens = jnp.argmax(got, -1)[:, None].astype(jnp.int32)
     assert max(LENGTHS) + TICKS <= PS * len(TABLES[0])
-
-
-def test_pallas_paged_attention_reads_the_same_pool():
-    """The Pallas paged-attention path (interpret mode here) reads each
-    layer's (n_pages, page_size, K, D) view of the lane-padded pool and
-    gives the XLA gather path's logits and pool, up to the rounding of
-    its streaming softmax."""
-    cfg = get_config("internvl2-1b", smoke=True)
-    xla = build_model(cfg, compute_dtype=F32)
-    pallas = build_model(cfg, compute_dtype=F32, paged_attn="pallas_interpret")
-    params = xla.init(jax.random.PRNGKey(0))
-    pools = xla.init_paged_cache(N_PAGES, PS, F32)
-    pools = jax.tree.map(
-        lambda a: a.at[..., :cfg.n_kv_heads * cfg.head_dim].set(
-            jax.random.normal(jax.random.PRNGKey(2), a.shape[:3]
-                              + (cfg.n_kv_heads * cfg.head_dim,))), pools)
-    args = (jnp.asarray([[5], [17], [0], [42]], jnp.int32), pools,
-            jnp.asarray(TABLES, jnp.int32), jnp.asarray(LENGTHS, jnp.int32))
-    want, want_pools, _ = jax.jit(xla.paged_decode_step)(params, *args)
-    got, got_pools, _ = jax.jit(pallas.paged_decode_step)(params, *args)
-    live = np.asarray(LENGTHS) > 0
-    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
-                               rtol=1e-5, atol=1e-5)
-    # layer 0 writes the same rows; later layers' rows carry the
-    # attention's rounding
-    for g, w in zip(jax.tree.leaves(got_pools), jax.tree.leaves(want_pools)):
-        np.testing.assert_array_equal(np.asarray(g[0]), np.asarray(w[0]))
-        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                   rtol=1e-5, atol=1e-5)
