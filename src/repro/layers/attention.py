@@ -25,10 +25,14 @@ NEG_INF = -2.0e38
 
 def attention_specs(d_model: int, n_heads: int, n_kv_heads: int, head_dim: int):
     return {
-        "wq": WSpec((d_model, n_heads, head_dim), ("embed", "heads", None)),
-        "wk": WSpec((d_model, n_kv_heads, head_dim), ("embed", "kv_heads", None)),
-        "wv": WSpec((d_model, n_kv_heads, head_dim), ("embed", "kv_heads", None)),
-        "wo": WSpec((n_heads, head_dim, d_model), ("heads", None, "embed")),
+        "wq": WSpec((d_model, n_heads, head_dim), ("embed", "heads", None),
+                    mxu_operand=True),
+        "wk": WSpec((d_model, n_kv_heads, head_dim),
+                    ("embed", "kv_heads", None), mxu_operand=True),
+        "wv": WSpec((d_model, n_kv_heads, head_dim),
+                    ("embed", "kv_heads", None), mxu_operand=True),
+        "wo": WSpec((n_heads, head_dim, d_model), ("heads", None, "embed"),
+                    mxu_operand=True),
     }
 
 

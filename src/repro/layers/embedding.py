@@ -19,7 +19,8 @@ def embed_apply(params, ids, *, scale: float = 1.0, dtype=jnp.bfloat16):
 
 
 def head_specs(d_model: int, vocab: int):
-    return {"w": WSpec((d_model, vocab), ("embed", "vocab"), init="small")}
+    return {"w": WSpec((d_model, vocab), ("embed", "vocab"), init="small",
+                       mxu_operand=True)}
 
 
 def head_apply(params, x, *, softcap: float = 0.0, tied_table=None):

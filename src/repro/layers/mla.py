@@ -17,6 +17,7 @@ API:
 from __future__ import annotations
 
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -31,26 +32,27 @@ NEG_INF = -2.0e38
 def mla_specs(cfg):
     H = cfg.n_heads
     qk = cfg.qk_nope_dim + cfg.qk_rope_dim
+    mm = partial(WSpec, mxu_operand=True)      # every projection is a matmul
     if cfg.q_lora_rank:
         q = {
-            "w_dq": WSpec((cfg.d_model, cfg.q_lora_rank), ("embed", "mla_rank")),
+            "w_dq": mm((cfg.d_model, cfg.q_lora_rank), ("embed", "mla_rank")),
             "q_norm": norm_specs(cfg.q_lora_rank),
-            "w_uq": WSpec((cfg.q_lora_rank, H, qk), ("mla_rank", "heads", None)),
+            "w_uq": mm((cfg.q_lora_rank, H, qk), ("mla_rank", "heads", None)),
         }
     else:
-        q = {"w_q": WSpec((cfg.d_model, H, qk), ("embed", "heads", None))}
+        q = {"w_q": mm((cfg.d_model, H, qk), ("embed", "heads", None))}
     return {
         **q,
-        "w_dkv": WSpec((cfg.d_model, cfg.kv_lora_rank), ("embed", "mla_rank")),
+        "w_dkv": mm((cfg.d_model, cfg.kv_lora_rank), ("embed", "mla_rank")),
         "kv_norm": norm_specs(cfg.kv_lora_rank),
-        "w_kr": WSpec((cfg.d_model, cfg.qk_rope_dim), ("embed", None)),
-        "w_uk": WSpec(
+        "w_kr": mm((cfg.d_model, cfg.qk_rope_dim), ("embed", None)),
+        "w_uk": mm(
             (cfg.kv_lora_rank, H, cfg.qk_nope_dim), ("mla_rank", "heads", None)
         ),
-        "w_uv": WSpec(
+        "w_uv": mm(
             (cfg.kv_lora_rank, H, cfg.v_head_dim), ("mla_rank", "heads", None)
         ),
-        "w_o": WSpec((H, cfg.v_head_dim, cfg.d_model), ("heads", None, "embed")),
+        "w_o": mm((H, cfg.v_head_dim, cfg.d_model), ("heads", None, "embed")),
     }
 
 

@@ -10,9 +10,9 @@ from repro.layers.initializers import WSpec
 
 def mlp_specs(d_model: int, d_ff: int):
     return {
-        "wi_gate": WSpec((d_model, d_ff), ("embed", "mlp")),
-        "wi_up": WSpec((d_model, d_ff), ("embed", "mlp")),
-        "wo": WSpec((d_ff, d_model), ("mlp", "embed")),
+        "wi_gate": WSpec((d_model, d_ff), ("embed", "mlp"), mxu_operand=True),
+        "wi_up": WSpec((d_model, d_ff), ("embed", "mlp"), mxu_operand=True),
+        "wo": WSpec((d_ff, d_model), ("mlp", "embed"), mxu_operand=True),
     }
 
 

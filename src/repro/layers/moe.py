@@ -60,9 +60,12 @@ def moe_specs(cfg):
     f = cfg.moe_d_ff or cfg.d_ff
     specs = {
         "router": WSpec((cfg.d_model, cfg.n_experts), (None, None), init="small"),
-        "wi_gate": WSpec((E, cfg.d_model, f), ("experts", "embed", "expert_mlp")),
-        "wi_up": WSpec((E, cfg.d_model, f), ("experts", "embed", "expert_mlp")),
-        "wo": WSpec((E, f, cfg.d_model), ("experts", "expert_mlp", "embed")),
+        "wi_gate": WSpec((E, cfg.d_model, f), ("experts", "embed", "expert_mlp"),
+                         mxu_operand=True),
+        "wi_up": WSpec((E, cfg.d_model, f), ("experts", "embed", "expert_mlp"),
+                       mxu_operand=True),
+        "wo": WSpec((E, f, cfg.d_model), ("experts", "expert_mlp", "embed"),
+                    mxu_operand=True),
     }
     if cfg.router_bias:
         specs["router_bias"] = WSpec((cfg.n_experts,), (None,), init="zeros")

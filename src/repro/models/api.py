@@ -210,11 +210,13 @@ def _lm_specs(cfg, stages):
         sp["head"] = head_specs(cfg.d_model, cfg.vocab_size)
     if cfg.has_vision_stub:
         sp["img_proj"] = {
-            "w": WSpec((cfg.d_model, cfg.d_model), (None, "embed"))
+            "w": WSpec((cfg.d_model, cfg.d_model), (None, "embed"),
+                       mxu_operand=True)
         }
     if cfg.mtp_depth:
         sp["mtp"] = {
-            "proj": WSpec((2 * cfg.d_model, cfg.d_model), (None, "embed")),
+            "proj": WSpec((2 * cfg.d_model, cfg.d_model), (None, "embed"),
+                          mxu_operand=True),
             "norm_h": norm_specs(cfg.d_model, cfg.norm),
             "norm_e": norm_specs(cfg.d_model, cfg.norm),
             "block": {

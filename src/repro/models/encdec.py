@@ -160,7 +160,8 @@ def build_encdec(cfg, mesh=None, rules=None, *, compute_dtype=jnp.bfloat16,
     n_dec = cfg.n_layers
 
     specs: dict[str, Any] = {
-        "audio_proj": {"w": WSpec((cfg.d_model, cfg.d_model), (None, "embed"))},
+        "audio_proj": {"w": WSpec((cfg.d_model, cfg.d_model), (None, "embed"),
+                                  mxu_operand=True)},
         "encoder": stack_specs(_enc_block_specs(cfg), cfg.n_encoder_layers),
         "enc_norm": norm_specs(cfg.d_model, cfg.norm),
         "embed": embed_specs(cfg.vocab_size, cfg.d_model),

@@ -114,6 +114,9 @@ class DecodeStream:
                                              module=module)
         self._c_batched = self.metrics.counter("decode.batched_picks",
                                                module=module)
+        self._g_rounded = self.metrics.gauge("decode.mxu_rounded_bytes",
+                                             module=module)
+        self._g_rounded.set(self.rt.mxu_rounded_bytes)
         # greedy rows' tokens in one device call over all rows' logits;
         # select_token is looked up now, so a patched one is traced
         self._pick = jax.jit(partial(select_token, rng=None,
@@ -476,6 +479,7 @@ class DecodeStream:
                 "decode_tokens": self.decode_tokens,
                 "prefills": self.prefills,
                 "cross_task_decode_batches": self.cross_task_decode_batches,
+                "mxu_rounded_bytes": int(self._g_rounded.value),
                 "decode_rows": self.rows.max_slots,
                 "live_rows": len(self.live),
                 "waiting": len(self.waiting),

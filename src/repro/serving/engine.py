@@ -27,6 +27,7 @@ import jax
 from repro.core.module import ModelSpec, ModuleSpec
 from repro.core.placement import Placement
 from repro.core.registry import ModuleRegistry
+from repro.layers.initializers import round_mxu_operands
 from repro.obs.trace import Span, Tracer
 
 
@@ -67,6 +68,9 @@ class DecoderRuntime:
     # the last prefill's or paged decode step's tokens per (MoE layer,
     # held expert), (0, 0) for a decoder without routed experts
     expert_counts: Any = None
+    # bytes of the bfloat16 matmul operands made from float32 params at
+    # deploy (``round_mxu_operands``); 0 where nothing was rounded
+    mxu_rounded_bytes: int = 0
 
     @property
     def n_prefix(self) -> int:
@@ -87,6 +91,13 @@ class InferenceResult:
     # simulator's per-request routes (s2m3.PlanReport.routes)
     devices: dict[str, str] = dataclasses.field(default_factory=dict)
     rid: int | None = None
+
+
+def _platform(dev) -> str:
+    """Platform of a jax.Device or of the devices of a Sharding."""
+    if isinstance(dev, jax.sharding.Sharding):
+        dev = next(iter(dev.device_set))
+    return dev.platform
 
 
 class S2M3Engine:
@@ -137,9 +148,15 @@ class S2M3Engine:
             dev = self._device_for(host)
             params = jax.device_put(params, dev)
             if hasattr(apply_or_bundle, "decode_step"):
-                # generative head: the builder returned a ModelBundle
+                # generative head: the builder returned a ModelBundle;
+                # its declared matmul weights are held as the MXU reads
+                # them, so no step rounds them again
                 bundle = apply_or_bundle
-                rt = DecoderRuntime(m, bundle, params, dev, host)
+                params, rounded = round_mxu_operands(
+                    params, bundle.specs, platform=_platform(dev),
+                    precision=jax.config.jax_default_matmul_precision)
+                rt = DecoderRuntime(m, bundle, params, dev, host,
+                                    mxu_rounded_bytes=rounded)
                 rt.prefill_jit = jax.jit(bundle.prefill)
                 # donated cache buffers: every decode step rebinds the
                 # cache, so the old buffer is reused in place

@@ -21,6 +21,7 @@ from jax.sharding import SingleDeviceSharding
 import chip_smoke
 from repro.common.config import ArchConfig, get_config
 from repro.common.profiling import memory_summary
+from repro.layers.initializers import round_mxu_operands
 from repro.models.api import build_model
 
 V5E_HBM = 16 * 1024**3
@@ -195,3 +196,89 @@ def test_paged_decode_step_writes_the_pool_in_place(cell, one_chip):
         assert pool_sized_moves(compiled.as_text(), n) == []
         temps.append(compiled.memory_analysis().temp_size_in_bytes)
     assert temps[1] <= temps[0], temps
+
+
+# Float32 matmul weights at the vlm-mt cells' shapes, full depth: 24
+# layers, 32 rows, 30 pages of 16 a row, 961 pages, a 20-token prompt
+# behind the 256-token image prefix.  At default precision the TPU reads
+# a float32 operand as bfloat16, so with float32 weights the compiler
+# converts each whole stacked weight in every run of a program; the
+# engine deploys the declared matmul weights already rounded
+# (``round_mxu_operands``), and the programs then convert none.
+VLM_ROWS, VLM_N_MAX, VLM_PAGES, VLM_PROMPT = 32, 30, 961, 20
+
+
+def stacked_weight_converts(hlo: str, shapes) -> list[str]:
+    """Instructions, fused ones included, that convert to a buffer with
+    one of ``shapes``, the stacked (per-layer) weights' shapes."""
+    out = []
+    for line in hlo.splitlines():
+        m = _INSTR.search(line)
+        if m is None or m.group(2) != "convert":
+            continue
+        dims = re.findall(r"\[([\d,]+)\]", m.group(1))
+        if any(tuple(map(int, d.split(","))) in shapes for d in dims):
+            out.append(line.strip()[:160])
+    return out
+
+
+def test_stacked_weight_converts_finds_a_hoisted_weight_convert():
+    """The check reads the compiled text as the TPU compiler writes it."""
+    hlo = """
+  %convert.10 = bf16[24,896,4864]{2,1,0:T(8,128)(2,1)} convert(%params__stages____blocks____blocks____mlp____wi_gate__.1), backend_config={}
+  %convert.6 = bf16[24,896,14,64]{1,3,2,0:T(8,128)(2,1)} convert(%custom-call.24)
+  ROOT %convert.29 = bf16[32,4864]{1,0:T(8,128)(2,1)} convert(%mul.585)
+  %fusion.106 = f32[32,151655]{1,0:T(8,128)} fusion(%p, %q), kind=kOutput
+  %copy.3 = bf16[24,896,4864]{2,1,0:T(8,128)(2,1)} copy(%convert.10)
+"""
+    shapes = {(24, 896, 4864), (24, 896, 14, 64)}
+    assert len(stacked_weight_converts(hlo, shapes)) == 2
+    assert stacked_weight_converts(hlo, {(24, 4864, 896)}) == []
+
+
+def _vlm_programs(b, chip, params):
+    """The compiled paged decode step and prefill of the vlm-mt decoder
+    ``b`` with ``params`` (abstract, on ``chip``)."""
+    cfg = b.cfg
+    pool = _on(jax.eval_shape(
+        lambda: b.init_paged_cache(VLM_PAGES, PAGE, jnp.float32)), chip)
+    step = jax.jit(b.paged_decode_step, donate_argnums=(2,)).lower(
+        params, _i32(chip, VLM_ROWS, 1), pool,
+        _i32(chip, VLM_ROWS, VLM_N_MAX), _i32(chip, VLM_ROWS)).compile()
+    span = -(-(cfg.n_image_tokens + VLM_PROMPT) // PAGE) * PAGE
+    batch = {"tokens": _i32(chip, 1, VLM_PROMPT),
+             "image_embeds": jax.ShapeDtypeStruct(
+                 (1, cfg.n_image_tokens, cfg.d_model), jnp.float32,
+                 sharding=chip)}
+    cache = _on(jax.eval_shape(
+        lambda: b.init_cache(1, span, jnp.float32)), chip)
+    prefill = jax.jit(b.prefill).lower(params, batch, cache).compile()
+    return {"paged_decode_step": step, "prefill": prefill}
+
+
+@pytest.fixture(scope="module")
+def vlm_weight_programs(bundle, one_chip):
+    """Both programs with the float32 tree and with the tree the engine
+    deploys on the TPU, and the stacked weights' shapes."""
+    f32 = bundle.abstract_params(jnp.float32)
+    deployed = jax.eval_shape(lambda p: round_mxu_operands(
+        p, bundle.specs, platform="tpu", precision=None)[0], f32)
+    blocks = f32["stages"]["blocks"]["blocks"]
+    shapes = {tuple(x.shape) for x in jax.tree.leaves(blocks)}
+    return (_vlm_programs(bundle, one_chip, _on(f32, one_chip)),
+            _vlm_programs(bundle, one_chip, _on(deployed, one_chip)), shapes)
+
+
+@pytest.mark.parametrize("program", ["paged_decode_step", "prefill"])
+def test_deployed_weights_leave_no_weight_convert(vlm_weight_programs,
+                                                  program):
+    """With float32 weights each of the seven stacked matmul weights
+    (wq, wk, wv, wo; wi_gate, wi_up, wo) is converted whole; with the
+    deployed tree none is, and the temporaries shrink."""
+    f32, deployed, shapes = vlm_weight_programs
+    found = stacked_weight_converts(f32[program].as_text(), shapes)
+    assert len(found) == 7, found
+    assert stacked_weight_converts(deployed[program].as_text(), shapes) == []
+    temps = [p[program].memory_analysis().temp_size_in_bytes
+             for p in (f32, deployed)]
+    assert temps[1] < temps[0], temps
